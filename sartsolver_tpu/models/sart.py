@@ -225,6 +225,17 @@ def _resolve_fused(
 # the last *compilation*, which is what provenance needs.
 FUSED_ENGAGEMENT = {"last": None}
 
+# Named scopes of the loop's device work (docs/OBSERVABILITY.md §4): every
+# operation traced inside one carries the name in its ``op_name`` metadata,
+# which the profiler's device trace keeps as each operation's ``tf_op``
+# path, so a trace reader charges device time to the Laplacian penalty or
+# to the RTM sweep whatever backend implements them. Metadata only: the
+# compiled programs run the same instructions with or without them (the
+# Pallas kernel's custom call takes the scope as its instruction name).
+SCOPE_LAPLACIAN = "sart.laplacian"
+SCOPE_SWEEP = "sart.sweep"
+
+
 def _momentum_carries_fitted(opts: SolverOptions) -> bool:
     """Whether the momentum state includes the previous iterate's forward
     projection. Only the linear solver on the classic (os_subsets == 1)
@@ -1365,6 +1376,14 @@ class _SweepContext:
                                         accum_dtype=self.dtype)
         return forward_project(self.rtm, f_, accum_dtype=self.dtype)
 
+    @jax.named_scope(SCOPE_SWEEP)
+    def loop_fp(self, f_):
+        """An iteration's own forward sweep ``H f``, voxel-axis reduced:
+        the unfused paths' second RTM read, and the log solver's
+        projection of the extrapolated point."""
+        return _psum(self.fp_any(f_), self.voxel_axis)
+
+    @jax.named_scope(SCOPE_LAPLACIAN)
     def compute_penalty(self, x):  # x: [B, V_local] (f, or log f — log variant)
         """``beta * L @ x`` for this device's voxel block.
 
@@ -1433,6 +1452,7 @@ class _SweepContext:
             outs.append(jnp.where(self.vmask_sub[t][None, :], obs_t, 0))
         return jnp.stack(outs, axis=1)
 
+    @jax.named_scope(SCOPE_SWEEP)
     def run_os_sweep(self, f, dk, ascale, g, meas_mask, obs_sub):
         """(f_upd, fitted_upd): one OUTER iteration of the ordered-subsets
         cycle (docs/PERFORMANCE.md §9) — ``os_subsets`` sub-updates, each
@@ -1705,6 +1725,7 @@ class _SweepContext:
             tripped = tripped | self.abft_residual(vred[1], red[2])
         return fsq, (~done) & tripped
 
+    @jax.named_scope(SCOPE_SWEEP)
     def run_sweep(self, f, fitted, penalty, dk, ascale, g, meas_mask, obs):
         """(f_upd, fitted_upd or None, bp_chk): the iteration's two RTM
         sweeps. ``dk`` is the schedule factor decay^k — a traced scalar in
@@ -1931,7 +1952,7 @@ def _solve_normalized_batch_impl(
                 if opts.logarithmic:
                     # no linearity to exploit — one forward projection of
                     # the extrapolated point per iteration
-                    fitted_base = _psum(kit.fp_any(y), voxel_axis)
+                    fitted_base = kit.loop_fp(y)
                 else:
                     # H y = H f + beta (H f - H f_prev), exact: the
                     # extrapolated residual costs no RTM read
@@ -1952,7 +1973,7 @@ def _solve_normalized_batch_impl(
                 done[:, None], fitted, _psum(fitted_upd, voxel_axis)
             )
         else:
-            fitted_new = _psum(kit.fp_any(f_new), voxel_axis)
+            fitted_new = kit.loop_fp(f_new)
         if opts.precise_convergence:
             fsq_local = _sumsq_precise(fitted_new, dtype)
         else:  # the reference CUDA path's fp32 dot (sartsolver_cuda.cpp:253)
@@ -2332,7 +2353,7 @@ def sched_step_normalized(
         else:
             if momentum:
                 if opts.logarithmic:
-                    fitted_base = _psum(kit.fp_any(y), voxel_axis)
+                    fitted_base = kit.loop_fp(y)
                 else:
                     fitted_base = fitted + beta * (fitted - fitted_prev)
             else:
@@ -2351,7 +2372,7 @@ def sched_step_normalized(
                 done[:, None], fitted, _psum(fitted_upd, voxel_axis)
             )
         else:
-            fitted_new = _psum(kit.fp_any(f_new), voxel_axis)
+            fitted_new = kit.loop_fp(f_new)
         if opts.precise_convergence:
             fsq_local = _sumsq_precise(fitted_new, dtype)
         else:

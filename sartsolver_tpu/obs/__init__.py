@@ -16,7 +16,9 @@ is that layer (docs/OBSERVABILITY.md):
   watchdog beacon stream (resilience/watchdog.py) plus explicit
   :func:`~sartsolver_tpu.obs.trace.span` context managers around the
   pipeline's host phases; exported as Chrome trace-event JSON loadable in
-  Perfetto alongside ``--profile_dir`` XLA traces.
+  Perfetto, and, while ``jax.profiler`` captures (``--profile_dir``),
+  written as ``TraceAnnotation`` spans on the host plane of the same
+  xplane as the device operations.
 - :mod:`~sartsolver_tpu.obs.schema` — the machine-readable record
   vocabulary (JSONL): one validated format shared by ``--metrics_out``
   run artifacts and ``bench.py``'s ``BENCH_*.json`` results.
@@ -43,7 +45,8 @@ imports only the standard library: ``bench.py``'s parent process — which
 must never import jax — loads :mod:`~sartsolver_tpu.obs.schema` by file
 path, and the registry is consulted from cold I/O paths where an import
 cycle or a heavyweight import would hurt. jax is imported lazily, inside
-the one function that needs it (multi-host aggregation).
+the one function that needs it (multi-host aggregation); ``span()`` finds
+the profiler through ``sys.modules`` and never imports it.
 """
 
 from sartsolver_tpu.obs.metrics import (  # noqa: F401

@@ -14,19 +14,29 @@ Two event sources, one buffer:
   begin/end pair, with optional key=value args carried into the event.
 
 The buffer renders to Chrome trace-event JSON (``ph: "X"`` complete
-events, microsecond timestamps) loadable in Perfetto / chrome://tracing
-alongside ``--profile_dir`` XLA traces.
+events, microsecond timestamps) loadable in Perfetto / chrome://tracing.
+
+One clock with the device: while ``jax.profiler`` is capturing
+(``--profile_dir``, or any caller's ``jax.profiler.trace``), every
+``span()`` is also a ``jax.profiler.TraceAnnotation`` with the same name
+and args, so it lands on the host plane of the profiler's xplane, on the
+clock of the device operations it dispatched or waited for. jax is looked
+up in ``sys.modules``, never imported: this package stays stdlib-only.
+Beacons and request-track spans stay in the buffer only.
 
 Cost model: with no buffer installed (the default) a beacon pays one
-module-global ``None`` check and ``span()`` returns a shared no-op
-context manager — nothing is recorded, nothing allocated per call. The
-CLI installs a buffer only when ``SART_TRACE_EVENTS`` is set.
+module-global ``None`` check; ``span()`` pays that and, once jax is
+imported, one ``TraceAnnotation.is_enabled()`` call, and returns a shared
+no-op context manager while the profiler is off — nothing is recorded,
+nothing allocated per call. The CLI installs a buffer only when
+``SART_TRACE_EVENTS`` is set.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -49,14 +59,17 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     def __init__(self, buffer: "TraceBuffer", name: str, cat: str,
-                 args: Dict[str, object]):
+                 args: Dict[str, object], annotation=None):
         self._buffer = buffer
         self._name = name
         self._cat = cat
         self._args = args
+        self._annotation = annotation  # profiler twin, or None
         self._t0 = 0.0
 
     def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -66,6 +79,8 @@ class _Span:
             time.perf_counter() - self._t0,
             threading.get_ident(), self._args,
         )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class TraceBuffer:
@@ -294,16 +309,30 @@ def uninstall() -> None:
     watchdog.set_beacon_tap(None)
 
 
-def span(name: str, cat: str = "host", **args):
-    """Context manager recording ``name`` as a complete trace event.
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while jax is imported and its
+    profiler is capturing, else None."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
 
-    Returns a shared no-op object when tracing is disabled — safe (and
-    cheap) to leave in production code paths, like the beacons.
+
+def span(name: str, cat: str = "host", **args):
+    """Context manager recording ``name`` as a complete trace event in
+    the buffer and, while the profiler captures, on its host plane.
+
+    Returns a shared no-op object when both are off — safe (and cheap)
+    to leave in production code paths, like the beacons.
     """
     buf = _buffer
+    annotation = _profiler_annotation()
+    if annotation is not None:
+        annotation = annotation(name, **args)
     if buf is None:
-        return _NULL_SPAN
-    return _Span(buf, name, cat, args)
+        return _NULL_SPAN if annotation is None else annotation
+    return _Span(buf, name, cat, args, annotation)
 
 
 class _RequestSpan:

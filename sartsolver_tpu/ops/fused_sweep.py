@@ -312,10 +312,9 @@ def sharded_panel_sweep(
     n_panels = V // bs
     # Observability (host-side, trace-time — runs once per compilation):
     # the panel/collective plan behind this compiled sweep, so the per-
-    # panel psum granularity is visible in --metrics_out / trace sinks
-    # without parsing HLO (docs/OBSERVABILITY.md §collective).
+    # panel psum granularity is visible in --metrics_out without parsing
+    # HLO (docs/OBSERVABILITY.md §collective).
     from sartsolver_tpu.obs import metrics as _obs_metrics
-    from sartsolver_tpu.obs import trace as _obs_trace
 
     reg = _obs_metrics.get_registry()
     reg.gauge("fused_panel_count", path="sharded_panel").set(n_panels)
@@ -323,11 +322,6 @@ def sharded_panel_sweep(
     reg.counter(
         "collectives_planned_total", collective="psum", site="panel_bp"
     ).inc(n_panels)
-    with _obs_trace.span(
-        "collective", what="panel_bp_psum_plan", panels=n_panels,
-        panel_voxels=bs,
-    ):
-        pass
 
     fitted = None
     f_new_parts = []
@@ -398,9 +392,8 @@ def _sparse_trace_obs(occupancy, n_panels: int, n_skipped: int,
     """Host-side trace-time observability of the sparse plan (runs once
     per compilation, like the sharded panel scan's collective plan):
     the occupancy fraction and the tiles each sweep will skip land in
-    --metrics_out / trace sinks without parsing HLO."""
+    --metrics_out without parsing HLO."""
     from sartsolver_tpu.obs import metrics as _obs_metrics
-    from sartsolver_tpu.obs import trace as _obs_trace
 
     n_row_tiles = occupancy.grid_shape[0]
     tiles_per_panel = (bs // occupancy.tile_cols) * n_row_tiles
@@ -411,12 +404,6 @@ def _sparse_trace_obs(occupancy, n_panels: int, n_skipped: int,
     reg.counter("sparse_tiles_skipped_total", path=host).inc(
         n_skipped * tiles_per_panel
     )
-    with _obs_trace.span(
-        "sparse", what="panel_skip_plan", host=host, panels=n_panels,
-        skipped=n_skipped, panel_voxels=bs,
-        occupancy=occupancy.occupancy_fraction(),
-    ):
-        pass
 
 
 def sparse_panel_sweep(

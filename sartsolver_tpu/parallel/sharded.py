@@ -63,18 +63,19 @@ from sartsolver_tpu.parallel.mesh import (
 )
 
 
-def _stage(host_array, mesh, spec) -> jax.Array:
+def _stage(host_array, mesh, spec, **span_args) -> jax.Array:
     """Host -> global sharded array; multi-host safe (device_put cannot
     target non-addressable devices). Named fault site ``device.put``: a
     staging failure (device OOM, a preempted/hung device runtime) is a
     per-solve-call hazard the CLI's frame isolation absorbs into FAILED
-    frames."""
+    frames. ``span_args`` ride the ``device.put`` span (a chain's
+    ``chain`` serial)."""
     from sartsolver_tpu.obs import trace as obs_trace
     from sartsolver_tpu.resilience import faults, watchdog
 
     watchdog.beacon(watchdog.PHASE_STAGE)  # staging-phase progress beacon
     faults.fire(faults.SITE_DEVICE_PUT)
-    with obs_trace.span("device.put"):
+    with obs_trace.span("device.put", **span_args):
         if jax.process_count() == 1:
             return jax.device_put(host_array, NamedSharding(mesh, spec))
         from sartsolver_tpu.parallel.multihost import make_global
@@ -121,8 +122,11 @@ class DeviceSolveResult:
     """
 
     def __init__(self, solver, solution_norm, norms, packed,
-                 solution_fetch=None, fitted_norm=None):
+                 solution_fetch=None, fitted_norm=None, chain=None):
         self._solver = solver
+        # the producing solve_chain's serial: its fetch spans carry it,
+        # tying each fetch to the chain.dispatch span that caused it
+        self._span_args = {} if chain is None else {"chain": chain}
         self.solution_norm = solution_norm  # [B, padded_nvoxel] fp32, device
         # loop-exit ``H @ solution_norm`` ([B or 1, padded_npixel], device,
         # P('pixels')-sharded): carried into the next warm-started solve so
@@ -151,7 +155,8 @@ class DeviceSolveResult:
             # result-fetch beacon: this D2H blocks until the device work
             # completed — the watchdog's canary for a wedged runtime
             watchdog.beacon(watchdog.PHASE_FETCH)
-            with obs_trace.span("result.fetch", what="scalars"):
+            with obs_trace.span("result.fetch", what="transfer",
+                                part="scalars", **self._span_args):
                 packed = np.asarray(self._packed)
             self._scalars = (
                 packed[0].astype(np.int32),
@@ -182,11 +187,15 @@ class DeviceSolveResult:
             from sartsolver_tpu.resilience import watchdog
 
             watchdog.beacon(watchdog.PHASE_FETCH)
-            with obs_trace.span("result.fetch", what="solution"):
-                sol = np.asarray(self._solution_fetch).astype(np.float64)
-            self._host = (
-                sol[:, : self._solver.nvoxel] * self.norms[:, None]
-            )
+            with obs_trace.span("result.fetch", what="transfer",
+                                part="solution", **self._span_args):
+                sol = np.asarray(self._solution_fetch)
+            with obs_trace.span("result.fetch", what="denormalize",
+                                **self._span_args):
+                self._host = (
+                    sol[:, : self._solver.nvoxel].astype(np.float64)
+                    * self.norms[:, None]
+                )
         return self._host
 
     def solution_fetcher(self, b: int):
@@ -685,6 +694,7 @@ class DistributedSARTSolver:
         producing DeviceSolveResult must stay able to fetch afterwards
         (the writer thread's lazy solution fetch)."""
         self._solve_fns = {}
+        self._chains = 0  # solve_chain serial, carried by the chain's spans
         self._ray_stats_fn = None
         self._ray_stats_snapshot = None
         self._rescale_fn = jax.jit(  # sart-lint: disable=SL004
@@ -1319,10 +1329,11 @@ class DistributedSARTSolver:
             )
         return G
 
-    def _stage_frames(self, G: np.ndarray, local: bool):
+    def _stage_frames(self, G: np.ndarray, local: bool, **span_args):
         """Stage B frames onto the mesh: ``(g_dev, norms [B], msqs [B])``.
 
-        Shared by :meth:`solve_batch` and :meth:`solve_chain`.
+        Shared by :meth:`solve_batch` and :meth:`solve_chain`;
+        ``span_args`` ride the ``device.put`` span.
         """
         if self.problem is None:
             raise ValueError(
@@ -1365,7 +1376,8 @@ class DistributedSARTSolver:
                 )
                 norms[b], msqs[b] = norm, msq
 
-            g_dev = _stage(g_stage, self.mesh, P(None, PIXEL_AXIS))
+            g_dev = _stage(g_stage, self.mesh, P(None, PIXEL_AXIS),
+                           **span_args)
         return g_dev, norms, msqs
 
     def _check_warm_alive(self, warm: DeviceSolveResult) -> None:
@@ -1433,6 +1445,17 @@ class DistributedSARTSolver:
         per-frame setup forward projection — one full RTM read saved per
         warm frame (models/sart fitted0 docs).
         """
+        from sartsolver_tpu.obs import trace as obs_trace
+
+        self._chains += 1
+        ids = {"chain": self._chains}
+        with obs_trace.span("chain.dispatch", frames=len(measurements),
+                            warm=int(warm is not None), **ids):
+            return self._dispatch_chain(measurements, f0, warm, local, ids)
+
+    def _dispatch_chain(self, measurements, f0, warm, local, ids):
+        """:meth:`solve_chain`'s host body, inside its ``chain.dispatch``
+        span; ``ids`` (the chain serial) ride every span it opens."""
         from sartsolver_tpu.resilience import faults, watchdog
 
         watchdog.beacon(watchdog.PHASE_DISPATCH)
@@ -1452,7 +1475,7 @@ class DistributedSARTSolver:
             )
         G = self._check_frames(measurements, local)
         K = G.shape[0]
-        g_dev, norms, msqs = self._stage_frames(G, local)
+        g_dev, norms, msqs = self._stage_frames(G, local, **ids)
         # carry renormalization between per-frame measurement norms
         rescale = np.ones(K)
         rescale[1:] = norms[:-1] / norms[1:]
@@ -1472,7 +1495,7 @@ class DistributedSARTSolver:
             f0_np = np.zeros((1, self.padded_nvoxel), dtype)
             if f0 is not None:
                 f0_np[0, : self.nvoxel] = np.asarray(f0, np.float64) / norms[0]
-            f0_dev = _stage(f0_np, self.mesh, P(None, VOXEL_AXIS))
+            f0_dev = _stage(f0_np, self.mesh, P(None, VOXEL_AXIS), **ids)
         res, fitted_fin = self._chain_fn(
             use_guess_first, with_fitted0=fitted0_dev is not None
         )(
@@ -1485,6 +1508,7 @@ class DistributedSARTSolver:
             self, res.solution, norms,
             self._pack_fn(res.status, res.iterations, res.convergence),
             solution_fetch=sol_fetch, fitted_norm=fitted_fin,
+            chain=ids["chain"],
         )
 
     def solve_batch(

@@ -136,7 +136,7 @@ def dispatch_guarded(
 
     watchdog.beacon(watchdog.PHASE_DISPATCH)
     try:
-        with obs_trace.span("solve.dispatch"):
+        with obs_trace.span("group.dispatch"):
             return dispatch(), None
     except Exception as err:
         if (

@@ -386,7 +386,7 @@ class ContinuousBatcher:
             try:
                 # the availability wrappers the classic loop gets from
                 # cli.py's dispatch_guarded call: dispatch-phase beacon +
-                # solve.dispatch trace span (ladder=None — the fixed lane
+                # group.dispatch trace span (ladder=None — the fixed lane
                 # count cannot halve, OOM handling is the leftover path)
                 with self._step_span(stats.strides):
                     dispatch_guarded(
