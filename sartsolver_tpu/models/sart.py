@@ -1389,7 +1389,8 @@ class _SweepContext:
 
         With a :class:`ShardedLaplacian` (2-D mesh driver) the penalty is
         halo-exchanged: block-diagonal triplets read only the local block
-        and boundary values travel in a compact export table — no
+        (as shifted diagonals where they lie on few, as a grid stencil
+        does) and boundary values travel in a compact export table — no
         ``[B, V_global]`` all_gather lives in the loop (VERDICT r2 weak #1).
         A plain :class:`LaplacianCOO` (single shard) indexes x directly.
         """

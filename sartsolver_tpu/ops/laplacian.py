@@ -7,6 +7,17 @@ The reference stores the Laplacian as flattened-index COO sorted by
 static-shape COO scatter-add: XLA lowers ``.at[rows].add`` to an on-device
 scatter; rows/cols/vals are padded to a static size so the op stays
 jit-stable across frames.
+
+A regular voxel grid indexed row-major puts its stencil's triplets on a
+handful of diagonals (the 7-point Laplacian of an nx×ny×nz grid: offsets
+0, ±1, ±nz and ±ny·nz). For the solver's penalty
+(:func:`shard_laplacian_halo` / :func:`sharded_penalty`) the block-local
+triplets are then stored as those diagonals instead — one coefficient row
+per distinct ``col − row`` offset — and the product becomes shifted slices
+and multiply-adds, with no gather and no scatter. The diagonal form is
+chosen at set-up whenever it takes no more bytes than the triplets it
+replaces (``D × block × itemsize ≤ nnz_loc × (8 + itemsize)``, ``D``
+distinct offsets); compactly indexed or random couplings keep COO.
 """
 
 from __future__ import annotations
@@ -70,12 +81,15 @@ class ShardedLaplacian(NamedTuple):
     ``(0, 0, 0.0)`` triplets / index-0 exports):
 
     - ``rows_loc, cols_loc, vals_loc`` — block-diagonal triplets; rows and
-      cols are block-local.
+      cols are block-local. Zero length when the diagonal form holds them.
     - ``rows_halo, gidx_halo, vals_halo`` — cross-block triplets; rows are
       block-local, ``gidx_halo`` indexes the gathered export table
       (``owner_shard * n_export + position``).
     - ``export_idx`` — block-local solution indices this shard publishes
       (the union of what every other shard needs from it).
+    - ``diag_offsets, diag_vals`` — the block-diagonal triplets in diagonal
+      form: ``diag_vals[k, i]`` sums the triplets ``(i, i + diag_offsets[k])``
+      (the same offsets on every shard). ``D = 0`` when COO is kept.
     """
 
     rows_loc: Array  # [S, nnz_loc] int32
@@ -85,6 +99,8 @@ class ShardedLaplacian(NamedTuple):
     gidx_halo: Array  # [S, nnz_halo] int32
     vals_halo: Array  # [S, nnz_halo] float
     export_idx: Array  # [S, n_export] int32
+    diag_offsets: Array  # [S, D] int32
+    diag_vals: Array  # [S, D, block] float
 
 
 def shard_laplacian_halo(
@@ -93,7 +109,10 @@ def shard_laplacian_halo(
     """Partition COO triplets into block-diagonal + halo sets (host-side).
 
     ``block`` is the padded per-shard voxel count; triplet indices are
-    global and must lie in ``[0, n_shards * block)``.
+    global and must lie in ``[0, n_shards * block)``. The block-diagonal
+    set takes the diagonal form when its ``D`` distinct offsets (over all
+    shards) take no more bytes than its triplets:
+    ``D * block * itemsize <= nnz_loc * (8 + itemsize)``.
     """
     rows = np.asarray(lap.rows, np.int64)
     cols = np.asarray(lap.cols, np.int64)
@@ -140,6 +159,31 @@ def shard_laplacian_halo(
 
     nnz_loc = max(1, max((len(v) for v in loc_v), default=0))
     nnz_halo = max((len(v) for v in halo_v), default=0)
+    # offsets present anywhere, by a count over [-(block-1), block-1]
+    loc_d = [c - r for r, c in zip(loc_r, loc_c)]
+    present = np.zeros(2 * block - 1, bool)
+    for d in loc_d:
+        present[d + block - 1] = True
+    offsets = np.flatnonzero(present) - (block - 1)
+    n_diag = len(offsets)
+    diag_form = 0 < n_diag and (
+        n_diag * block * np_dtype.itemsize
+        <= nnz_loc * (8 + np_dtype.itemsize)
+    )
+    if diag_form:
+        # duplicates sum into one coefficient; bincount is O(nnz)
+        slot = np.cumsum(present) - 1  # offset + block - 1 -> diagonal k
+        diag_vals = np.stack([
+            np.bincount(slot[d + block - 1] * block + r,
+                        weights=v.astype(np.float64),
+                        minlength=n_diag * block).reshape(n_diag, block)
+            for r, d, v in zip(loc_r, loc_d, loc_v)
+        ]).astype(np_dtype)
+        nnz_loc = 0
+        loc_r = loc_c = loc_v = [np.empty(0)] * n_shards
+    else:
+        offsets = offsets[:0]
+        diag_vals = np.zeros((n_shards, 0, block), np_dtype)
     return ShardedLaplacian(
         padded(loc_r, nnz_loc),
         padded(loc_c, nnz_loc),
@@ -148,7 +192,20 @@ def shard_laplacian_halo(
         padded(halo_g, nnz_halo),
         padded(halo_v, nnz_halo, 0.0, np_dtype),
         padded(exports, n_export),
+        np.tile(offsets.astype(np.int32), (n_shards, 1)),
+        diag_vals,
     )
+
+
+def diagonal_share(lap: LaplacianCOO, slap: ShardedLaplacian,
+                   block: int) -> float:
+    """Share of ``lap``'s triplets that ``slap`` applies in diagonal form:
+    the block-local ones where it took that form, else none."""
+    if slap.diag_offsets.shape[-1] == 0 or lap.nnz == 0:
+        return 0.0
+    rows = np.asarray(lap.rows, np.int64)
+    cols = np.asarray(lap.cols, np.int64)
+    return float(np.count_nonzero(rows // block == cols // block)) / lap.nnz
 
 
 def sharded_penalty(slap: ShardedLaplacian, x: Array, axis_name) -> Array:
@@ -159,9 +216,14 @@ def sharded_penalty(slap: ShardedLaplacian, x: Array, axis_name) -> Array:
     communication is the compact boundary all_gather — skipped entirely
     when the partition has no cross-block triplets.
     """
-    pen = jnp.zeros_like(x).at[:, slap.rows_loc].add(
-        slap.vals_loc.astype(x.dtype)[None, :] * x[:, slap.cols_loc]
-    )
+    if slap.diag_offsets.shape[-1]:
+        pen = _diagonal_matvec(slap.diag_offsets, slap.diag_vals, x)
+    else:
+        pen = jnp.zeros_like(x)
+    if slap.rows_loc.shape[-1]:
+        pen = pen.at[:, slap.rows_loc].add(
+            slap.vals_loc.astype(x.dtype)[None, :] * x[:, slap.cols_loc]
+        )
     if slap.rows_halo.shape[-1] == 0 or axis_name is None:
         return pen
     table = lax.all_gather(
@@ -170,6 +232,21 @@ def sharded_penalty(slap: ShardedLaplacian, x: Array, axis_name) -> Array:
     return pen.at[:, slap.rows_halo].add(
         slap.vals_halo.astype(x.dtype)[None, :] * table[:, slap.gidx_halo]
     )
+
+
+def _diagonal_matvec(offsets: Array, vals: Array, x: Array) -> Array:
+    """``pen[:, i] = sum_k vals[k, i] * x[:, i + offsets[k]]``: one shifted
+    slice of the zero-padded block per diagonal (|offset| < block). A
+    coefficient of zero adds nothing, as an absent triplet adds nothing to
+    the scatter-add, even where ``x`` holds ``-inf`` (``log 0``)."""
+    n = x.shape[-1]
+    xp = jnp.pad(x, ((0, 0), (n, n)))
+    pen = jnp.zeros_like(x)
+    for k in range(vals.shape[0]):
+        v = vals[k].astype(x.dtype)[None, :]
+        xs = lax.dynamic_slice_in_dim(xp, n + offsets[k], n, axis=1)
+        pen = pen + jnp.where(v != 0, v * xs, 0)
+    return pen
 
 
 def coo_matvec(lap: LaplacianCOO | None, x: Array, nvoxel: int) -> Array:

@@ -50,6 +50,7 @@ from sartsolver_tpu.models.sart import (
 from sartsolver_tpu.ops.laplacian import (
     LaplacianCOO,
     ShardedLaplacian,
+    diagonal_share,
     shard_laplacian_halo,
 )
 from sartsolver_tpu.parallel.mesh import (
@@ -648,8 +649,23 @@ class DistributedSARTSolver:
             # compact export table instead of a [B, V_global] all_gather of
             # the solution every iteration (ops/laplacian.py). A 1-shard
             # mesh degenerates to all-local triplets, no communication.
+            # Block-local triplets on few diagonals (a grid stencil) are
+            # stored as those diagonals: no gather, no scatter.
+            from sartsolver_tpu.obs import flight as _obs_flight
+            from sartsolver_tpu.obs import metrics as _obs_metrics
+
             sharded_lap = shard_laplacian_halo(
                 laplacian, self.n_voxel_shards, self.voxel_block, dtype
+            )
+            share = diagonal_share(laplacian, sharded_lap, self.voxel_block)
+            _obs_metrics.get_registry().gauge(
+                "laplacian_diagonal_share").set(share)
+            n_diag = sharded_lap.diag_offsets.shape[-1]
+            _obs_flight.record_event(
+                "laplacian",
+                f"{n_diag} diagonals serve {share:.4f} of the triplets"
+                if n_diag else "COO: the offsets fail the byte criterion",
+                n_diagonals=int(n_diag), diagonal_share=share,
             )
             lap_spec = P(VOXEL_AXIS, None)
             laplacian = ShardedLaplacian(

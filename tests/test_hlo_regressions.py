@@ -153,6 +153,56 @@ def test_no_full_solution_gather_inside_voxel_sharded_loop():
     )
 
 
+@pytest.mark.parametrize("coupling, diagonal", [
+    ("grid", True), ("random", False),
+])
+def test_grid_laplacian_loop_has_no_gather_or_scatter(coupling, diagonal):
+    """A grid stencil's penalty is applied as diagonals: the one-shard
+    solver's compiled while body holds no gather and no scatter. A random
+    coupling keeps the COO scatter-add, which the same check sees."""
+    from jax.sharding import NamedSharding, PartitionSpec as PS
+
+    from sartsolver_tpu.parallel.mesh import make_mesh
+    from sartsolver_tpu.parallel.sharded import DistributedSARTSolver
+
+    if coupling == "grid":  # 8 x 8 x 16 = V voxels, 7-point stencil
+        idx = np.arange(V).reshape(8, 8, 16)
+        pairs = [(idx.take(np.arange(1, n), axis=ax).ravel(),
+                  idx.take(np.arange(n - 1), axis=ax).ravel())
+                 for ax, n in enumerate(idx.shape)]
+        a = np.concatenate([p[0] for p in pairs] + [p[1] for p in pairs])
+        b = np.concatenate([p[1] for p in pairs] + [p[0] for p in pairs])
+        rows = np.r_[np.arange(V), a]
+        cols = np.r_[np.arange(V), b]
+        vals = np.r_[np.bincount(a, minlength=V), -np.ones(a.size)]
+    else:
+        rng = np.random.default_rng(2)
+        rows = rng.integers(0, V, 600)
+        cols = rng.integers(0, V, 600)
+        vals = rng.standard_normal(600)
+    H = np.random.default_rng(1).random((P, V), np.float32)
+    opts = SolverOptions(max_iterations=4, conv_tolerance=1e-30,
+                         fused_sweep="off", beta_laplace=0.01)
+    s = DistributedSARTSolver(
+        H, make_laplacian(rows, cols, vals.astype(np.float32)), opts=opts,
+        mesh=make_mesh(1, 1))
+    g = jax.device_put(
+        np.ones((1, s.padded_npixel), np.float32),
+        NamedSharding(s.mesh, PS(None, "pixels")),
+    )
+    f0 = jax.device_put(
+        np.zeros((1, s.padded_nvoxel), np.float32),
+        NamedSharding(s.mesh, PS(None, "voxels")),
+    )
+    txt = s._batch_fn(True).lower(
+        s.problem, g, jnp.ones(1, jnp.float32), f0
+    ).compile().as_text()
+    assert hlo.while_body_names(txt), "no while loop found in HLO"
+    loop_ops = hlo.op_histogram(txt, loop_only=True)
+    found = sorted(op for op in loop_ops if op in ("gather", "scatter"))
+    assert found == ([] if diagonal else ["gather", "scatter"]), found
+
+
 def test_no_codes_copy_inside_int8_loop():
     """The int8 loop must stream only the 1-byte codes: no matrix-sized
     transpose/copy (s8 or dequantized f32/bf16) may live inside the while

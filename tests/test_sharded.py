@@ -51,7 +51,8 @@ def test_halo_laplacian_partition_matches_dense():
             type(slap)(*(a[0] for a in sl)), xb, "voxels"
         ),
         mesh=mesh,
-        in_specs=(type(slap)(*(P("voxels", None),) * 7), P(None, "voxels")),
+        in_specs=(type(slap)(*(P("voxels", None),) * len(type(slap)._fields)),
+                  P(None, "voxels")),
         out_specs=P(None, "voxels"),
         check_vma=False,
     ))(slap, x)

@@ -131,5 +131,8 @@ def test_chain_program_names_its_layers(path, opts):
                            _chain_program_text(solver, frames)))
     laplacian = {n for n in names if f"/{SCOPE_LAPLACIAN}/" in n}
     sweep = {n for n in names if f"/{SCOPE_SWEEP}/" in n}
-    assert any(n.endswith(("scatter-add", "gather")) for n in laplacian), path
+    # the chain stencil's triplets take the diagonal form: shifted slices
+    # in place of the COO gather and scatter-add (ops/laplacian.py)
+    assert solver.problem.laplacian.diag_offsets.shape[-1] == 3, path
+    assert any(n.endswith("dynamic_slice") for n in laplacian), path
     assert any("dot_general" in n or "pallas_call" in n for n in sweep), path
