@@ -117,7 +117,12 @@ class SolveResult(NamedTuple):
 
 
 def _psum(x, axis_name):
-    return lax.psum(x, axis_name) if axis_name is not None else x
+    """``lax.psum`` over ``axis_name`` under the scope ``sart.exchange``;
+    ``x`` itself where no axis is given (a size-1 mesh axis)."""
+    if axis_name is None:
+        return x
+    with jax.named_scope(SCOPE_EXCHANGE):
+        return lax.psum(x, axis_name)
 
 
 def _resolve_fused(
@@ -232,8 +237,15 @@ FUSED_ENGAGEMENT = {"last": None}
 # to the RTM sweep whatever backend implements them. Metadata only: the
 # compiled programs run the same instructions with or without them (the
 # Pallas kernel's custom call takes the scope as its instruction name).
+# ``sart.exchange`` names the cross-chip collectives (the psums, the
+# Laplacian's halo all_gather). Inside the sweep or the penalty it nests
+# under their scope, so a reader keyed on either still counts it there;
+# the forward projection's voxel-axis psum and the convergence psums sit
+# under it alone. A size-1 mesh axis has no collective, so no op of a
+# one-chip program carries it.
 SCOPE_LAPLACIAN = "sart.laplacian"
 SCOPE_SWEEP = "sart.sweep"
+SCOPE_EXCHANGE = "sart.exchange"
 
 
 def _momentum_carries_fitted(opts: SolverOptions) -> bool:
@@ -1398,7 +1410,8 @@ class _SweepContext:
         if isinstance(lap, ShardedLaplacian):
             return self.beta * sharded_penalty(lap, x, self.voxel_axis)
         if self.voxel_axis is not None and lap is not None:
-            x = lax.all_gather(x, self.voxel_axis, tiled=True, axis=1)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                x = lax.all_gather(x, self.voxel_axis, tiled=True, axis=1)
         return self.beta * jax.vmap(
             lambda xb: coo_matvec(lap, xb, self.nvoxel)
         )(x)

@@ -239,6 +239,14 @@ def fused_available(npixel: int, nvoxel: int, rtm_itemsize: int, batch: int = 1)
 # (parallel/sharded.py: sharded_fused_batch).
 
 
+def _psum(x, axis_name):
+    """The solver core's psum (models/sart.py, imported here late: it
+    imports this module), named by its collective scope ``sart.exchange``."""
+    from sartsolver_tpu.models.sart import _psum as psum
+
+    return psum(x, axis_name)
+
+
 def pick_panel_voxels(
     npixel: int, nvoxel: int, itemsize: int, batch: int = 1
 ) -> int:
@@ -332,7 +340,7 @@ def sharded_panel_sweep(
             # (|codes| <= 127 in bf16), panel-sized — never a full-matrix
             # convert (the audit's loop_convert_threshold pins this)
             panel = panel.astype(jnp.bfloat16)
-        bp = jax.lax.psum(
+        bp = _psum(
             jax.lax.dot_general(
                 w, panel,
                 dimension_numbers=(((1,), (0,)), ((), ())),
@@ -481,7 +489,7 @@ def sparse_panel_sweep(
             preferred_element_type=jnp.float32,
         )
         if axis_name is not None:
-            bp = jax.lax.psum(bp, axis_name)
+            bp = _psum(bp, axis_name)
         f_new_p = update_fn(f_p, bp, *aux_p)
         f_new_parts.append(f_new_p)
         fwd = f_new_p if fwd_scale is None else f_new_p * aux_p[fwd_scale]
@@ -534,7 +542,7 @@ def sparse_gather_sweep(
             preferred_element_type=jnp.float32,
         )
         if axis_name is not None:
-            bp = jax.lax.psum(bp, axis_name)
+            bp = _psum(bp, axis_name)
         f_p = jax.lax.dynamic_slice_in_dim(f, start, bs, axis=1)
         aux_p = [jax.lax.dynamic_slice_in_dim(a, start, bs, axis=1)
                  for a in aux]
@@ -619,7 +627,7 @@ def sparse_os_back(
         ))
     bp = jnp.concatenate(parts, axis=1)
     if axis_name is not None:
-        bp = jax.lax.psum(bp, axis_name)
+        bp = _psum(bp, axis_name)
     if scale is not None:
         bp = bp * scale[None, :]
     return bp
@@ -707,7 +715,7 @@ def os_subset_back(
         preferred_element_type=jnp.float32,
     )
     if axis_name is not None:
-        bp = jax.lax.psum(bp, axis_name)
+        bp = _psum(bp, axis_name)
     if scale is not None:
         bp = bp * scale[None, :]
     return bp

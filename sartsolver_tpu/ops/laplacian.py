@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import Array, lax
@@ -68,10 +69,14 @@ class ShardedLaplacian(NamedTuple):
     partitioning both rows and columns by voxel block, almost every triplet
     is block-diagonal — computable purely from the shard's own solution
     block. The few cross-block triplets only need the *boundary* values,
-    which travel in a compact static export table (one small all_gather of
-    ``[B, n_shards * n_export]`` instead of ``[B, V_global]``). A worst-case
-    dense coupling degrades gracefully toward the full gather; a
-    block-diagonal split needs no communication at all.
+    which travel in a compact static export table: one all_gather of
+    ``[B, n_shards * n_export]`` per penalty instead of ``[B, V_global]``,
+    ``n_export`` being the most boundary values any shard publishes (two
+    grid planes for a middle shard of a 3-D stencil). The cross-block
+    triplets stay COO (a gather from the table and a scatter-add) even
+    where the block-local ones take the diagonal form. A worst-case dense
+    coupling degrades gracefully toward the full gather; a block-diagonal
+    split needs no communication at all.
 
     Host-built by :func:`shard_laplacian_halo` with a leading shard
     dimension on every field; inside ``shard_map`` each device slices its
@@ -213,8 +218,10 @@ def sharded_penalty(slap: ShardedLaplacian, x: Array, axis_name) -> Array:
 
     ``x`` is the batched local solution block ``[B, voxel_block]``; fields
     of ``slap`` are this device's slices (no leading shard dim). The only
-    communication is the compact boundary all_gather — skipped entirely
-    when the partition has no cross-block triplets.
+    communication is the boundary all_gather, ``[B, n_shards * n_export]``
+    under the scope ``sart.exchange``, skipped entirely when the partition
+    has no cross-block triplets. Its triplets are applied as COO (gather
+    and scatter-add) whether or not the block-local ones are diagonals.
     """
     if slap.diag_offsets.shape[-1]:
         pen = _diagonal_matvec(slap.diag_offsets, slap.diag_vals, x)
@@ -226,9 +233,13 @@ def sharded_penalty(slap: ShardedLaplacian, x: Array, axis_name) -> Array:
         )
     if slap.rows_halo.shape[-1] == 0 or axis_name is None:
         return pen
-    table = lax.all_gather(
-        x[:, slap.export_idx], axis_name, axis=1, tiled=True
-    )  # [B, S * n_export]
+    from sartsolver_tpu.models.sart import SCOPE_EXCHANGE
+
+    # the export table is packed by a local gather, outside the scope: the
+    # scope names the collective alone
+    export = x[:, slap.export_idx]  # [B, n_export]
+    with jax.named_scope(SCOPE_EXCHANGE):
+        table = lax.all_gather(export, axis_name, axis=1, tiled=True)
     return pen.at[:, slap.rows_halo].add(
         slap.vals_halo.astype(x.dtype)[None, :] * table[:, slap.gidx_halo]
     )

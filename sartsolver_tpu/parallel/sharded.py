@@ -658,14 +658,18 @@ class DistributedSARTSolver:
                 laplacian, self.n_voxel_shards, self.voxel_block, dtype
             )
             share = diagonal_share(laplacian, sharded_lap, self.voxel_block)
-            _obs_metrics.get_registry().gauge(
-                "laplacian_diagonal_share").set(share)
+            # the most cross-shard triplets any shard holds (the halo's COO)
+            halo = int(sharded_lap.rows_halo.shape[-1])
+            registry = _obs_metrics.get_registry()
+            registry.gauge("laplacian_diagonal_share").set(share)
+            registry.gauge("laplacian_halo_triplets").set(halo)
             n_diag = sharded_lap.diag_offsets.shape[-1]
             _obs_flight.record_event(
                 "laplacian",
                 f"{n_diag} diagonals serve {share:.4f} of the triplets"
                 if n_diag else "COO: the offsets fail the byte criterion",
                 n_diagonals=int(n_diag), diagonal_share=share,
+                halo_triplets=halo,
             )
             lap_spec = P(VOXEL_AXIS, None)
             laplacian = ShardedLaplacian(
