@@ -79,6 +79,8 @@ _HELP = {
     "nonfinite_pixels_total": "Non-finite measurement pixels dropped.",
     "fused_panel_count": "Panels per sweep in the panel-psum plan.",
     "fused_panel_voxels": "Voxels per panel in the panel-psum plan.",
+    "fused_sweep_contraction":
+        "1 for the unit (vpu|mxu) the fused kernel contracts panels on.",
     "collectives_planned_total":
         "Collectives in the compiled sweep, by site.",
     "fault_trips_total": "Injected faults tripped (SART_FAULT).",

@@ -10,24 +10,37 @@ sartsolver_cuda.cpp:239-249):
 
 As two XLA matmuls the RTM — the tens-to-hundreds-of-GB operand the whole
 design revolves around — is streamed from HBM **twice** per iteration, and
-since both sweeps are gemv-shaped the MXU is bandwidth-bound, so that factor
-of two is the whole game. This Pallas kernel tiles the voxel axis and keeps
-each column panel ``H[:, j*bs:(j+1)*bs]`` resident in VMEM for *both* uses:
+both sweeps are gemv-shaped (a handful of flops per matrix element), so
+the HBM stream is the bound to reach. This Pallas kernel tiles the voxel
+axis and keeps each column panel ``H[:, j*bs:(j+1)*bs]`` resident in VMEM
+for *both* uses:
 
     for each voxel panel j:              (grid, panels DMA-pipelined)
-        bp_j      = w @ H_panel          (MXU, contraction over pixels)
+        bp_j      = w @ H_panel          (contraction over pixels)
         f_new_j   = update(f_j, bp_j, aux_j...)   (VPU)
-        fitted   += f_new_j @ H_panel^T  (MXU, accumulated in VMEM)
+        fitted   += f_new_j @ H_panel^T  (accumulated in VMEM)
 
 halving the HBM bill of the hot loop. The elementwise ``update`` is a
 trace-time closure, so the linear (Eq. 2) and logarithmic (Eq. 3) variants
 specialize the same kernel the way the reference specializes
 UpdateSolutionKernel / UpdateLogSolutionKernel (sart_kernels.cu:205-224).
 
+Which unit contracts a panel depends on what the MXU would have to do
+with it. An fp32 panel at a small batch (up to ``_VPU_MAX_BATCH``
+frames) contracts on the VPU, in fp32 multiply-adds over row chunks: four
+VPU operations an element, which hide under the panel's DMA. On the MXU the
+same matvec needs fp32 precision, that is ``Precision.HIGHEST``, a
+multi-pass bf16 emulation that re-splits the panel in both dots every
+iteration and left the kernel contraction-bound at 69% of the HBM bound
+(measured on v5e at 6144x524288, PERF.md §6). Larger batches, where
+the MXU's cost does not grow with the batch and the VPU's does, and bf16
+and int8 panels (one MXU pass, no split), stay on the MXU.
+
 The Pallas kernel requires the full pixel extent of the panel on this
-device (the back-projection psum would have to run between the two MXU
-ops). Voxel-axis sharding composes fine: each device fuses over its column
-block and the forward-projection psum runs on the kernel's output.
+device (the back-projection psum would have to run between the panel's
+two contractions). Voxel-axis sharding composes fine: each device fuses
+over its column block and the forward-projection psum runs on the
+kernel's output.
 Pixel-axis sharding gets the same one-HBM-read structure from
 :func:`sharded_panel_sweep` instead: a plain-XLA voxel-panel scan that
 psums each panel's back-projection over the pixel axis *between* the
@@ -90,6 +103,14 @@ _PANEL_BYTES_TARGET_INT8 = _env_bytes(
     1 << 20, 12 << 20)
 _MIN_BLOCK_VOXELS = 128  # lane width
 _SUBLANE = 8  # fp32 sublane width
+# Largest batch whose fp32 panels contract on the VPU (_sweep_kernel_vpu);
+# above it, and for bf16/int8 panels, the MXU contracts them. Measured on
+# v5e at the 6144x524288 block (PERF.md §6): the VPU form took 18.6 ms at
+# B=1 and at B=2, 28.3 ms at B=4 and 85 ms at B=8, where the MXU form
+# took 22.8-23.0 ms at every B.
+_VPU_MAX_BATCH = 2
+# Pixel rows a step of the VPU form's row loop takes (a multiple of 8).
+_VPU_ROWS = 128
 
 # XLA charges a Pallas kernel's entire VMEM footprint (double-buffered
 # operand/output blocks, scratch, plus any operands/results XLA itself
@@ -113,6 +134,13 @@ _SCOPED_VMEM_EST_CAP_BYTES = 48 << 20
 _VOXEL_PANEL_OPERANDS = 10
 
 
+def _contracts_on_vpu(itemsize: int, batch: int) -> bool:
+    """Whether the kernel contracts its panels on the VPU: fp32 panels
+    (the only 4-byte storage) at ``batch <= _VPU_MAX_BATCH``. Otherwise
+    the MXU does (see :func:`_sweep_kernel_vpu`)."""
+    return itemsize == 4 and batch <= _VPU_MAX_BATCH
+
+
 def _scoped_vmem_estimate(
     npixel: int, nvoxel: int, bs: int, itemsize: int, batch: int
 ) -> int:
@@ -126,14 +154,20 @@ def _scoped_vmem_estimate(
     S(1) placement). bf16 panels feed the MXU directly (no conversion
     scratch — see _sweep_kernel); int8 panels dequantize to a bf16 scratch
     copy in VMEM (measured: int8 bs=512 needs 16.39M at B=1, over the
-    16M default). fp32 panels contract at HIGHEST precision, which
-    Mosaic charges about four more fp32 panels for (compiled for a
+    16M default). fp32 panels on the MXU contract at HIGHEST precision,
+    which Mosaic charges about four more fp32 panels for (compiled for a
     described v5e, PR 21: 23.96M at 8192 pixels, bs=128, B=1, where the
-    default precision fit the 16M default)."""
+    default precision fit the 16M default). fp32 panels on the VPU need
+    instead the [B, P, 128] fitted accumulator and the lane-padded [P, B]
+    weight column (described v5e, 2026-10: at 6144 pixels, bs=256,
+    15.09M at B=1 against 36.25M at HIGHEST, 18.10M at B=2; at 8192
+    pixels, bs=128, 12.07M at B=1 and 16.07M at B=2)."""
+    vpu = _contracts_on_vpu(itemsize, batch)
     return (
         2 * npixel * bs * itemsize
         + (npixel * bs * 2 if itemsize == 1 else 0)
-        + (4 * npixel * bs * 4 if itemsize == 4 else 0)
+        + (4 * npixel * bs * 4 if itemsize == 4 and not vpu else 0)
+        + ((batch + 1) * npixel * _MIN_BLOCK_VOXELS * 4 if vpu else 0)
         + 2 * _VOXEL_PANEL_OPERANDS * batch * bs * 4
         + 2 * batch * npixel * 4
         + batch * (nvoxel + npixel) * 4
@@ -793,10 +827,11 @@ def _sweep_kernel(update_fn, n_aux, fwd_scale, rtm_ref, w_ref, f_ref, *rest):
         # forward operand below, so the loop's math is exactly fp32 SART on
         # the quantized matrix.
         panel = panel.astype(jnp.bfloat16)
-    # An fp32 panel contracts at fp32. Mosaic's default precision is one
-    # bf16 MXU pass: on v5e it left a 2.3e-3 relative back-projection
-    # error, against 3.7e-7 for XLA's fp32 dot, and moved frame 0 of the
-    # headline series from 103 to 72 iterations (PR 21, PERF.md §6).
+    # An fp32 panel here (a batch above _VPU_MAX_BATCH) contracts at fp32.
+    # Mosaic's default precision is one bf16 MXU pass: on v5e it left a
+    # 2.3e-3 relative back-projection error, against 3.7e-7 for XLA's fp32
+    # dot, and moved frame 0 of the headline series from 103 to 72
+    # iterations. HIGHEST emulates fp32 in several bf16 passes.
     precision = (jax.lax.Precision.HIGHEST
                  if panel.dtype == jnp.float32 else None)
     # Back-projection of this panel: contraction over the full pixel axis.
@@ -831,6 +866,79 @@ def _sweep_kernel(update_fn, n_aux, fwd_scale, rtm_ref, w_ref, f_ref, *rest):
         fitted_ref[...] += contrib
 
 
+# The VPU form of the kernel: fp32 panels at batch <= _VPU_MAX_BATCH. Both
+# contractions are matvecs, so each is one fp32 multiply and one add an
+# element on the VPU, with no bf16 split and no transpose of the panel.
+# Four VPU operations an element hide under the panel's DMA: at the
+# 6144x524288 block the kernel times as a body that only touches the
+# panel, 18.1-18.6 ms against 22.8 ms at HIGHEST on the MXU (v5e, PERF.md
+# §6). The rows run in _VPU_ROWS-row steps of a loop; shorter steps
+# measured slower than the MXU (the loop's own cost: 40 ms at 32 rows).
+def _sweep_kernel_vpu(update_fn, n_aux, fwd_scale, rtm_ref, wcol_ref, f_ref,
+                      *rest):
+    aux_refs = rest[:n_aux]
+    f_new_ref, fitted_ref, acc_ref = rest[n_aux:]
+    P, bs = rtm_ref.shape
+    B = f_ref.shape[0]
+    n_steps, tail = divmod(P, _VPU_ROWS)  # the tail is a multiple of 8
+
+    def over_rows(step, carry):
+        """``step(rows, carry)`` over the panel's pixel rows: the loop's
+        full steps, then the tail rows."""
+        if n_steps:
+            carry = jax.lax.fori_loop(0, n_steps, lambda i, c: step(pl.ds(
+                pl.multiple_of(i * _VPU_ROWS, _VPU_ROWS), _VPU_ROWS), c),
+                carry)
+        return step(pl.ds(n_steps * _VPU_ROWS, tail), carry) if tail else carry
+
+    # Back-projection: bp[b] = sum over pixel rows of panel * w[b], the
+    # pixel weights broadcast along lanes from the [P, B] column. Each
+    # sublane of an [8, bs] accumulator sums every eighth row.
+    def bp_step(rows, accs):
+        panel = rtm_ref[rows, :]
+        w = wcol_ref[rows, :]
+        return tuple(
+            acc + (panel * w[:, b:b + 1]).reshape(
+                -1, _SUBLANE, bs).sum(axis=0)
+            for b, acc in enumerate(accs)
+        )
+
+    zero = jnp.zeros((_SUBLANE, bs), jnp.float32)
+    accs = over_rows(bp_step, (zero,) * B)
+    bp = jnp.concatenate([a.sum(axis=0, keepdims=True) for a in accs])
+    f_new = update_fn(f_ref[...], bp, *[a[...] for a in aux_refs])
+    f_new_ref[...] = f_new
+    fwd = f_new if fwd_scale is None else f_new * aux_refs[fwd_scale][...]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # Forward projection: the panel times fwd[b], its 128-lane blocks
+    # summed in registers, added to acc[b] — one add a lane a panel. The
+    # lanes are summed once, after the last panel, down the sublanes of
+    # acc[b]^T (a lane reduction straight to [1, P] took Mosaic 13.2 s to
+    # compile at 6144 pixels, the transpose 0.2 s: PERF.md §6).
+    def fwd_step(rows, carry):
+        panel = rtm_ref[rows, :]
+        for b in range(B):
+            prod = panel * fwd[b:b + 1, :]
+            part = prod[:, :_MIN_BLOCK_VOXELS]
+            for k in range(1, bs // _MIN_BLOCK_VOXELS):
+                part = part + prod[:, k * _MIN_BLOCK_VOXELS:
+                                   (k + 1) * _MIN_BLOCK_VOXELS]
+            acc_ref[b, rows, :] += part
+        return carry
+
+    over_rows(fwd_step, 0)
+
+    @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+    def _():
+        for b in range(B):
+            fitted_ref[b:b + 1, :] = jnp.sum(acc_ref[b].T, axis=0,
+                                             keepdims=True)
+
+
 def fused_sweep(
     rtm: Array,  # [P, V]
     w: Array,  # [B, P] fp32 — back-projection pixel weights
@@ -858,24 +966,39 @@ def fused_sweep(
             "VMEM-fittable; gate calls with fused_available()."
         )
     grid = (V // bs,)
+    vpu = _contracts_on_vpu(rtm.dtype.itemsize, B)
+    # Observability (host-side, trace-time): which unit contracts the
+    # compiled kernel's panels.
+    from sartsolver_tpu.obs import metrics as _obs_metrics
+
+    reg = _obs_metrics.get_registry()
+    reg.gauge("fused_sweep_contraction", path="vpu").set(float(vpu))
+    reg.gauge("fused_sweep_contraction", path="mxu").set(float(not vpu))
 
     voxel_panel = lambda b: pl.BlockSpec((b, bs), lambda j: (0, j))
+    resident = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
     in_specs = [
         pl.BlockSpec((P, bs), lambda j: (0, j)),  # RTM column panel
-        pl.BlockSpec((B, P), lambda j: (0, 0)),  # w: resident across panels
+        # w: resident across panels; a [P, B] column on the VPU path
+        resident((P, B) if vpu else (B, P)),
         voxel_panel(B),  # f
         *[voxel_panel(a.shape[0]) for a in aux],
     ]
     out_specs = (
         voxel_panel(B),  # f_new
-        pl.BlockSpec((B, P), lambda j: (0, 0)),  # fitted accumulator
+        resident((B, P)),  # fitted
     )
-    kernel = functools.partial(_sweep_kernel, update_fn, len(aux), fwd_scale)
+    if vpu:
+        kernel, w_in = _sweep_kernel_vpu, w.T
+        scratch = [pltpu.VMEM((B, P, _MIN_BLOCK_VOXELS), jnp.float32)]
+    else:
+        kernel, w_in, scratch = _sweep_kernel, w, []
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, update_fn, len(aux), fwd_scale),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
+        scratch_shapes=scratch,
         out_shape=(
             jax.ShapeDtypeStruct((B, V), jnp.float32),
             jax.ShapeDtypeStruct((B, P), jnp.float32),
@@ -886,7 +1009,7 @@ def fused_sweep(
             transcendentals=0,
         ),
         interpret=interpret,
-    )(rtm, w, f, *aux)
+    )(rtm, w_in, f, *aux)
 
 
 # --------------------------------------------------------------------------

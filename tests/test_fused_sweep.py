@@ -12,7 +12,9 @@ import numpy as np
 import pytest
 
 from sartsolver_tpu.config import SolverOptions
-from sartsolver_tpu.ops.fused_sweep import fused_available, pick_block_voxels
+from sartsolver_tpu.ops.fused_sweep import (
+    _VPU_MAX_BATCH, fused_available, pick_block_voxels,
+)
 from sartsolver_tpu.ops.laplacian import make_laplacian
 
 
@@ -71,22 +73,48 @@ def _solve(H, g, opts, lap=None, batch=None):
     )
 
 
+def _retrace():
+    """Drop compiled solvers, so the next solve traces the kernel anew
+    (the contraction is chosen at trace time)."""
+    import jax
+
+    from sartsolver_tpu.models import sart
+
+    sart._jitted_solver.cache_clear()
+    jax.clear_caches()
+
+
+def _assert_fused_tracks_unfused(monkeypatch, H, g, base, lap=None):
+    """Both forms of the fused kernel against the two-matmul path, element
+    by element: the MXU form (``_VPU_MAX_BATCH`` 0) and the VPU form, which
+    sums each contraction in another order than XLA's dot (it reads at most
+    7.6e-6 from the two-matmul solve here, on entries up to 2.5)."""
+    from sartsolver_tpu.ops import fused_sweep as fs
+
+    ref = _solve(H, g, dataclasses.replace(base, fused_sweep="off"), lap)
+    for vpu_limit in (0, fs._VPU_MAX_BATCH):
+        monkeypatch.setattr(fs, "_VPU_MAX_BATCH", vpu_limit)
+        _retrace()
+        fus = _solve(H, g, dataclasses.replace(base, fused_sweep="interpret"),
+                     lap)
+        assert int(ref.iterations) == int(fus.iterations)
+        assert int(ref.status) == int(fus.status)
+        np.testing.assert_allclose(
+            np.asarray(fus.solution), np.asarray(ref.solution),
+            rtol=2e-5, atol=2e-6,
+        )
+
+
 @pytest.mark.parametrize("logarithmic", [False, True])
 @pytest.mark.parametrize("with_lap", [False, True])
-def test_fused_matches_unfused(logarithmic, with_lap):
+def test_fused_matches_unfused(monkeypatch, logarithmic, with_lap):
     H, g = _case()
-    lap = _laplacian() if with_lap else None
     base = SolverOptions(
         max_iterations=30, conv_tolerance=1e-12, logarithmic=logarithmic,
         beta_laplace=1e-3 if with_lap else 0.0, relaxation=0.7,
     )
-    ref = _solve(H, g, dataclasses.replace(base, fused_sweep="off"), lap)
-    fus = _solve(H, g, dataclasses.replace(base, fused_sweep="interpret"), lap)
-    assert int(ref.iterations) == int(fus.iterations)
-    assert int(ref.status) == int(fus.status)
-    np.testing.assert_allclose(
-        np.asarray(fus.solution), np.asarray(ref.solution), rtol=2e-5, atol=2e-6
-    )
+    _assert_fused_tracks_unfused(
+        monkeypatch, H, g, base, _laplacian() if with_lap else None)
 
 
 @pytest.mark.parametrize("logarithmic", [False, True])
@@ -179,8 +207,9 @@ def test_scoped_vmem_model():
     # the B=1 headline configs also clear the raise cap
     assert fused_available(P, V, 4, batch=1)
     assert fused_available(P, V, 2, batch=1)
-    # fp32 contracts at HIGHEST precision, whose operand splits pushed
-    # the B=1 headline kernel to 23.96M (described v5e compile, PR 21)
+    # fp32 at B=1 contracts on the VPU: 12.07M at this shape (described
+    # v5e compile), under the default, but the estimate's margin still
+    # asks for the raise
     assert fused_compile_options(P, V, 4, batch=1) == opt
     # a tiny problem fits the default budget: no options needed
     assert fused_compile_options(8, 256, 4, batch=1) is None
@@ -188,13 +217,44 @@ def test_scoped_vmem_model():
     assert not fused_available(P, V, 4, batch=4096)
 
 
-@pytest.mark.parametrize("rtm_dtype,precision", [
-    ("float32", "HIGHEST"), ("bfloat16", None),
+def test_scoped_vmem_model_vpu_path(monkeypatch):
+    """The VPU form's estimate (fitted accumulator and weight column in
+    place of HIGHEST's four panels) stays above what Mosaic charged in
+    described-v5e compiles (the smallest scoped-VMEM limit that
+    compiles). The cell's block asks for the raised limit, a bound and
+    not an allocation, though its 15.09M fit the default."""
+    from sartsolver_tpu.ops import fused_sweep as fs
+
+    MiB = 1 << 20
+    charged = {  # (P, V, B) -> MiB
+        (6144, 524288, 1): 15.09, (6144, 524288, 2): 18.10,
+        (8192, 65536, 1): 12.07, (8192, 65536, 2): 16.07,
+    }
+    for (P_, V_, B_), mib in charged.items():
+        assert fs._contracts_on_vpu(4, B_)
+        bs = fs.pick_block_voxels(P_, V_, 4, B_)
+        assert fs._scoped_vmem_estimate(P_, V_, bs, 4, B_) >= mib * MiB
+    assert fs.pick_block_voxels(6144, 524288, 4) == 256
+    assert fs.fused_compile_options(6144, 524288, 4) == fs.raised_vmem_options()
+    vpu = fs._scoped_vmem_estimate(6144, 524288, 256, 4, 1)
+    monkeypatch.setattr(fs, "_VPU_MAX_BATCH", 0)
+    highest = fs._scoped_vmem_estimate(6144, 524288, 256, 4, 1)
+    assert highest >= 36.25 * MiB > vpu  # the HIGHEST kernel's charge
+
+
+@pytest.mark.parametrize("rtm_dtype,batch,want", [
+    ("float32", 1, "no dot"),
+    ("float32", _VPU_MAX_BATCH, "no dot"),
+    ("float32", _VPU_MAX_BATCH + 1, "HIGHEST"),
+    ("bfloat16", 1, None),
+    ("int8", 1, None),
 ])
-def test_kernel_contracts_fp32_panels_at_fp32(rtm_dtype, precision):
-    """Mosaic's default dot precision is one bf16 pass (a 2.3e-3 relative
-    back-projection error on v5e, PR 21): both of the kernel's dots ask
-    for HIGHEST on an fp32 panel. A bf16 panel keeps the default."""
+def test_kernel_contracts_fp32_panels_at_fp32(rtm_dtype, batch, want):
+    """An fp32 panel contracts at fp32. Up to ``_VPU_MAX_BATCH`` frames
+    the kernel contracts it on the VPU in fp32 multiply-adds, with no dot
+    at all; above it both MXU dots ask for HIGHEST, since Mosaic's default
+    is one bf16 pass (a 2.3e-3 relative back-projection error on v5e).
+    bf16 and int8 panels keep the default-precision dots."""
     import jax
     import jax.numpy as jnp
 
@@ -203,8 +263,8 @@ def test_kernel_contracts_fp32_panels_at_fp32(rtm_dtype, precision):
     jaxpr = jax.make_jaxpr(
         lambda r, w, f: fused_sweep(r, w, f, [], lambda fp, bp: fp + bp,
                                     interpret=True)
-    )(jnp.ones((8, 256), rtm_dtype), jnp.ones((1, 8), jnp.float32),
-      jnp.zeros((1, 256), jnp.float32))
+    )(jnp.ones((8, 256), rtm_dtype), jnp.ones((batch, 8), jnp.float32),
+      jnp.zeros((batch, 256), jnp.float32))
     def dots(jp):
         for eqn in jp.eqns:
             if eqn.primitive.name == "dot_general":
@@ -214,8 +274,135 @@ def test_kernel_contracts_fp32_panels_at_fp32(rtm_dtype, precision):
                 if hasattr(sub, "eqns"):
                     yield from dots(sub)
 
-    want = (jax.lax.Precision.HIGHEST,) * 2 if precision else None
-    assert list(dots(jaxpr.jaxpr)) == [want, want]
+    found = list(dots(jaxpr.jaxpr))
+    if want == "no dot":
+        assert found == []
+    else:
+        prec = (jax.lax.Precision.HIGHEST,) * 2 if want else None
+        assert found == [prec, prec]
+
+
+def _sweep64(H, w, f, aux, logarithmic, eps=1e-30):
+    """One SART sweep in float64 NumPy, in the solver's update forms
+    (models/sart.py ``_lin_update`` / ``_log_update``, alpha 1)."""
+    H = H.astype(np.float64)
+    w, f = w.astype(np.float64), f.astype(np.float64)
+    aux = [a.astype(np.float64) for a in aux]
+    bp = w @ H
+    if logarithmic:
+        vm, obs, *pen = aux
+        f_new = f * (obs + eps) / (bp * vm + eps)
+        if pen:
+            f_new = f_new * np.exp(-pen[0])
+    else:
+        invd, *pen = aux
+        f_new = f + invd * bp
+        if pen:
+            f_new = f_new - pen[0]
+        f_new = np.maximum(f_new, 0.0)
+    return f_new, f_new @ H.T
+
+
+@pytest.mark.parametrize("P_", [136, 256])
+@pytest.mark.parametrize("logarithmic", [False, True])
+@pytest.mark.parametrize("with_lap", [False, True])
+def test_vpu_contraction_matches_float64(monkeypatch, P_, logarithmic,
+                                         with_lap):
+    """The VPU contraction (fp32, B = 1) over a multi-panel grid: f_new
+    and fitted within 2e-6 of float64 NumPy, relative to their largest
+    entry. 136 pixel rows take one step of the 128-row loop and an 8-row
+    tail, 256 two steps and no tail; the shrunk panel target gives eight
+    128-voxel panels."""
+    import jax
+    import jax.numpy as jnp
+
+    from sartsolver_tpu.ops import fused_sweep as fs
+
+    V_ = 1024
+    monkeypatch.setattr(fs, "_PANEL_BYTES_TARGET", 16 << 10)
+    assert fs.pick_block_voxels(P_, V_, 4) == 128
+    assert fs._contracts_on_vpu(4, 1) and fs._VPU_ROWS == 128
+    rng = np.random.default_rng(P_ + 2 * logarithmic + with_lap)
+    H = rng.uniform(0.0, 1.0, (P_, V_)).astype(np.float32)
+    f = rng.uniform(0.5, 2.0, (1, V_)).astype(np.float32)
+    if logarithmic:
+        w = rng.uniform(0.5, 1.5, (1, P_)).astype(np.float32)
+        aux = [np.ones((1, V_), np.float32),
+               rng.uniform(0.5, 1.5, (1, V_)).astype(np.float32) * P_ / 2]
+        eps = 1e-30
+
+        def update(f_p, bp_p, vm_p, obs_p, *pen_p):
+            ratio = (obs_p + eps) / (bp_p * vm_p + eps)
+            return f_p * ratio * jnp.exp(-pen_p[0]) if pen_p else f_p * ratio
+    else:
+        w = rng.normal(0.0, 1.0, (1, P_)).astype(np.float32)
+        aux = [np.full((1, V_), 1.0 / P_, np.float32)]
+
+        def update(f_p, bp_p, invd_p, *pen_p):
+            upd = f_p + invd_p * bp_p
+            if pen_p:
+                upd = upd - pen_p[0]
+            return jnp.maximum(upd, 0)
+    if with_lap:
+        aux.append(rng.uniform(-1e-2, 1e-2, (1, V_)).astype(np.float32))
+    f_new, fitted = jax.jit(
+        lambda r, w_, f_, *a: fs.fused_sweep(r, w_, f_, list(a), update,
+                                             interpret=True)
+    )(H, w, f, *aux)
+    ref_f, ref_fit = _sweep64(H, w, f, aux, logarithmic)
+    for got, ref in ((f_new, ref_f), (fitted, ref_fit)):
+        err = np.abs(np.asarray(got, np.float64) - ref).max()
+        assert err <= 2e-6 * np.abs(ref).max()
+
+
+def test_vpu_contraction_batched(monkeypatch):
+    """The VPU form's batch lanes (each its own bp accumulator and fitted
+    scratch row) agree with float64 NumPy at the largest batch it takes;
+    64 pixel rows are the row loop's tail alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from sartsolver_tpu.ops import fused_sweep as fs
+
+    monkeypatch.setattr(fs, "_PANEL_BYTES_TARGET", 16 << 10)
+    B_ = _VPU_MAX_BATCH
+    assert fs._contracts_on_vpu(4, B_)
+    rng = np.random.default_rng(11)
+    H = rng.uniform(0.0, 1.0, (64, 512)).astype(np.float32)
+    w = rng.normal(0.0, 1.0, (B_, 64)).astype(np.float32)
+    f = rng.uniform(0.5, 2.0, (B_, 512)).astype(np.float32)
+    invd = np.full((1, 512), 1.0 / 64, np.float32)
+    f_new, fitted = jax.jit(lambda r, w_, f_, a: fs.fused_sweep(
+        r, w_, f_, [a], lambda fp, bp, i: jnp.maximum(fp + i * bp, 0),
+        interpret=True))(H, w, f, invd)
+    ref_f, ref_fit = _sweep64(H, w, f, [invd], False)
+    for got, ref in ((f_new, ref_f), (fitted, ref_fit)):
+        err = np.abs(np.asarray(got, np.float64) - ref).max()
+        assert err <= 2e-6 * np.abs(ref).max()
+
+
+def _contraction_gauge():
+    from sartsolver_tpu.obs import metrics as obs_metrics
+
+    reg = obs_metrics.get_registry()
+    return {p: reg.gauge("fused_sweep_contraction", path=p).value
+            for p in ("vpu", "mxu")}
+
+
+@pytest.mark.parametrize("rtm_dtype,batch,path", [
+    ("float32", 1, "vpu"),
+    ("bfloat16", 1, "mxu"),
+    ("float32", _VPU_MAX_BATCH + 1, "mxu"),
+])
+def test_contraction_gauge(rtm_dtype, batch, path):
+    """``fused_sweep_contraction{path}`` names the unit the compiled
+    kernel contracts its panels on: 1 for that path, 0 for the other."""
+    H, g = _case(seed=5)
+    opts = SolverOptions(max_iterations=3, conv_tolerance=0.0,
+                         rtm_dtype=rtm_dtype, fused_sweep="interpret")
+    _solve(H, g, opts, batch=None if batch == 1 else batch)
+    other = "mxu" if path == "vpu" else "vpu"
+    assert _contraction_gauge() == {path: 1.0, other: 0.0}
 
 
 def test_block_picker_steps_down_to_fit_vmem_cap():
@@ -242,8 +429,8 @@ def test_block_picker_steps_down_to_fit_vmem_cap():
 def test_minimum_panel_solve_matches_unfused(monkeypatch):
     """Numerics of the minimum-panel fallback path: shrink the panel-bytes
     target so the target-derived width is 0 and the picker's 128-voxel
-    clamp engages, then assert the fused (interpret) solve still matches
-    the unfused reference bit-for-tolerance — the same path a tall RTM
+    clamp engages, then assert the fused (interpret) solve still tracks
+    the unfused reference (both contraction forms) — the same path a tall RTM
     (or a tall per-chip shard of a voxel-major mesh) takes on hardware."""
     from sartsolver_tpu.ops import fused_sweep as fs
 
@@ -253,12 +440,7 @@ def test_minimum_panel_solve_matches_unfused(monkeypatch):
 
     H, g = _case(seed=7)
     base = SolverOptions(max_iterations=25, conv_tolerance=1e-12)
-    ref = _solve(H, g, dataclasses.replace(base, fused_sweep="off"))
-    fus = _solve(H, g, dataclasses.replace(base, fused_sweep="interpret"))
-    assert int(ref.iterations) == int(fus.iterations)
-    np.testing.assert_allclose(
-        np.asarray(fus.solution), np.asarray(ref.solution), rtol=2e-5, atol=2e-6
-    )
+    _assert_fused_tracks_unfused(monkeypatch, H, g, base)
 
 
 def test_block_picker_tall_matrices_keep_minimum_panel():

@@ -64,7 +64,8 @@ def _compile(fn, *args, options=None):
 
 @pytest.mark.parametrize("rtm_dtype,batch", [
     (jnp.bfloat16, 1), (jnp.float32, 1), (jnp.int8, 1), (jnp.bfloat16, 32),
-], ids=["bf16-B1", "fp32-B1", "int8-B1", "bf16-B32"])
+    (jnp.float32, 2), (jnp.float32, 3),
+], ids=["bf16-B1", "fp32-B1", "int8-B1", "bf16-B32", "fp32-B2", "fp32-B3"])
 def test_fused_sweep_compiles_for_v5e(one_chip, rtm_dtype, batch):
     itemsize = jnp.dtype(rtm_dtype).itemsize
     int8 = rtm_dtype == jnp.int8

@@ -135,4 +135,9 @@ def test_chain_program_names_its_layers(path, opts):
     # in place of the COO gather and scatter-add (ops/laplacian.py)
     assert solver.problem.laplacian.diag_offsets.shape[-1] == 3, path
     assert any(n.endswith("dynamic_slice") for n in laplacian), path
-    assert any("dot_general" in n or "pallas_call" in n for n in sweep), path
+    # the contraction under the scope: dots (two matmuls, panel scans,
+    # the MXU kernel), or the VPU kernel's multiply-adds, which fp32
+    # panels at B = 1 take, inside the interpreted kernel's grid loop
+    in_loop = {n.rsplit("/", 1)[-1] for n in sweep if "/while/body/" in n}
+    assert (any("dot_general" in n or "pallas_call" in n for n in sweep)
+            or {"mul", "reduce_sum"} <= in_loop), path
