@@ -178,9 +178,10 @@ def build_parser() -> argparse.ArgumentParser:
                           "solution, the solver loop inside): one host "
                           "round trip per N frames instead of per frame, "
                           "with per-frame results identical to serial "
-                          "dispatch. 1 disables. Applies to the default "
-                          "warm-start loop, including --multihost runs; "
-                          "ignored with --no_guess/--batch_frames.")
+                          "dispatch. 1 dispatches one frame per program "
+                          "through the same grouped loop. Applies to the "
+                          "default warm-start loop, including --multihost "
+                          "runs; ignored with --no_guess/--batch_frames.")
     tpu.add_argument("--rtm_dtype", default=None,
                      choices=["float32", "bfloat16", "float64", "int8"],
                      help="On-device RTM storage dtype. bfloat16 halves the "
@@ -191,8 +192,9 @@ def build_parser() -> argparse.ArgumentParser:
                           "sharded meshes alike).")
     tpu.add_argument("--profile_dir", default=None,
                      help="Write a jax.profiler trace of the frame loop "
-                          "here. Each frame (serial path) / scheduler "
-                          "stride (batched path) is wrapped in a "
+                          "here. Each dispatched frame group (one frame "
+                          "at group size 1) / scheduler stride "
+                          "(continuous batching) is wrapped in a "
                           "StepTraceAnnotation, so the XLA device trace "
                           "aligns with obs spans and frame serials instead "
                           "of one undifferentiated blob.")
@@ -470,6 +472,69 @@ def _validate(args) -> None:
                  "core already thresholds it — drop one.")
 
 
+def schedule_stride(args) -> int:
+    """Continuous-batching stride: flag > SART_SCHEDULE_STRIDE env > 16.
+    Resolved here (not in SolverOptions) so the env override is CLI
+    policy, like the other SART_* knobs."""
+    import os
+
+    from sartsolver_tpu.config import SartInputError
+
+    if args.schedule_stride is not None:
+        stride = args.schedule_stride
+    else:
+        env = os.environ.get("SART_SCHEDULE_STRIDE", "16")
+        try:
+            stride = int(env)
+        except ValueError:
+            # fail loudly like --schedule_stride would — a silently
+            # ignored operator typo on a perf knob is worse than exit 1
+            raise SartInputError(
+                f"SART_SCHEDULE_STRIDE must be an integer >= 1, "
+                f"{env!r} given."
+            )
+    if stride < 1:
+        raise SartInputError(
+            f"SART_SCHEDULE_STRIDE must be >= 1, {stride} given."
+        )
+    return stride
+
+
+def solver_options(args, *, schedule_stride: int, integrity: bool):
+    """``SolverOptions`` from validated args (``_validate`` has resolved
+    the sparse/lowrank env defaults): the fp64 CPU-parity profile under
+    ``--use_cpu``, else the fp32 device profile."""
+    from sartsolver_tpu.config import SolverOptions
+
+    common = dict(
+        logarithmic=args.logarithmic,
+        ray_density_threshold=args.ray_density_threshold,
+        ray_length_threshold=args.ray_length_threshold,
+        conv_tolerance=args.conv_tolerance,
+        beta_laplace=args.beta_laplace,
+        relaxation=args.relaxation,
+        relaxation_decay=args.relaxation_decay,
+        max_iterations=args.max_iterations,
+        divergence_recovery=args.divergence_recovery,
+        schedule_stride=schedule_stride,
+        integrity=integrity,
+        os_subsets=args.os_subsets,
+        momentum=args.momentum,
+        # forwarded on both profiles, so an explicit --fused_sweep on fails
+        # loudly under --use_cpu (the fused sweep is fp32-only) instead of
+        # silently degrading to the unfused path
+        fused_sweep=args.fused_sweep,
+    )
+    if args.use_cpu:
+        return SolverOptions.cpu_parity(**common)
+    return SolverOptions(
+        **common,
+        rtm_dtype=args.rtm_dtype,
+        sparse_rtm=args.sparse_rtm,
+        lowrank_rtm=args.lowrank_rtm,
+    )
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:]) if argv is None else list(argv)
     if argv and argv[0] == "lint":
@@ -557,12 +622,11 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     configure_compilation_cache()
 
-    from sartsolver_tpu.resilience import degrade, shutdown, watchdog
+    from sartsolver_tpu.resilience import shutdown, watchdog
     from sartsolver_tpu.resilience import integrity as integ_mod
     from sartsolver_tpu.resilience.failures import (
-        EXIT_INFRASTRUCTURE, EXIT_INTERRUPTED, FRAME_FAILED,
-        RECOVERABLE_FRAME_ERRORS, FrameFailure, OutputWriteError, RunSummary,
-        WatchdogTimeout, failed_row,
+        EXIT_INFRASTRUCTURE, EXIT_INTERRUPTED, RECOVERABLE_FRAME_ERRORS,
+        OutputWriteError, RunSummary, WatchdogTimeout,
     )
     from sartsolver_tpu.resilience.retry import (
         RetriesExhausted, reset_retry_stats,
@@ -619,7 +683,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     mh.install_pod_liveness()
 
     from sartsolver_tpu.config import (
-        SDC_DETECTED, SartInputError, SolverOptions, parse_time_intervals,
+        SartInputError, parse_time_intervals,
     )
     from sartsolver_tpu.io import hdf5files as hf
     from sartsolver_tpu.io.image import CompositeImage
@@ -780,30 +844,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         _mark("validate + index inputs")
 
-        # Continuous-batching stride: flag > SART_SCHEDULE_STRIDE env >
-        # the SolverOptions default (16). Resolved here (not in the
-        # dataclass) so the env override is CLI policy, like the other
-        # SART_* knobs; validation is the dataclass's.
-        import os as _os_stride
-
-        if args.schedule_stride is not None:
-            schedule_stride = args.schedule_stride
-        else:
-            _stride_env = _os_stride.environ.get("SART_SCHEDULE_STRIDE", "16")
-            try:
-                schedule_stride = int(_stride_env)
-            except ValueError:
-                # fail loudly like --schedule_stride would — a silently
-                # ignored operator typo on a perf knob is worse than exit 1
-                raise SartInputError(
-                    f"SART_SCHEDULE_STRIDE must be an integer >= 1, "
-                    f"{_stride_env!r} given."
-                )
-        if schedule_stride < 1:
-            raise SartInputError(
-                f"SART_SCHEDULE_STRIDE must be >= 1, "
-                f"{schedule_stride} given."
-            )
         # Numerical-integrity layer (docs/RESILIENCE.md §8): flag or env.
         # configure() switches the ingest-side digests (library code has
         # no opts object at stripe level); the in-solve ABFT check rides
@@ -814,48 +854,12 @@ def main(argv: Optional[List[str]] = None) -> int:
             integ_mod.SdcEscalation(on_event=note_event)
             if integrity_on else None
         )
+        opts = solver_options(args, schedule_stride=schedule_stride(args),
+                              integrity=integrity_on)
         if args.use_cpu:
-            opts = SolverOptions.cpu_parity(
-                logarithmic=args.logarithmic,
-                ray_density_threshold=args.ray_density_threshold,
-                ray_length_threshold=args.ray_length_threshold,
-                conv_tolerance=args.conv_tolerance,
-                beta_laplace=args.beta_laplace,
-                relaxation=args.relaxation,
-                relaxation_decay=args.relaxation_decay,
-                max_iterations=args.max_iterations,
-                divergence_recovery=args.divergence_recovery,
-                schedule_stride=schedule_stride,
-                integrity=integrity_on,
-                os_subsets=args.os_subsets,
-                momentum=args.momentum,
-                # forwarded so an explicit --fused_sweep on fails loudly
-                # (the fused sweep is fp32-only) instead of silently
-                # degrading to the unfused path
-                fused_sweep=args.fused_sweep,
-            )
             jax.config.update("jax_enable_x64", True)
             devices = jax.devices("cpu")
         else:
-            opts = SolverOptions(
-                logarithmic=args.logarithmic,
-                ray_density_threshold=args.ray_density_threshold,
-                ray_length_threshold=args.ray_length_threshold,
-                conv_tolerance=args.conv_tolerance,
-                beta_laplace=args.beta_laplace,
-                relaxation=args.relaxation,
-                relaxation_decay=args.relaxation_decay,
-                max_iterations=args.max_iterations,
-                divergence_recovery=args.divergence_recovery,
-                schedule_stride=schedule_stride,
-                integrity=integrity_on,
-                os_subsets=args.os_subsets,
-                momentum=args.momentum,
-                rtm_dtype=args.rtm_dtype,
-                fused_sweep=args.fused_sweep,
-                sparse_rtm=args.sparse_rtm,
-                lowrank_rtm=args.lowrank_rtm,
-            )
             devices = jax.devices()
 
         lap = None
@@ -1219,15 +1223,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             else contextlib.nullcontext()
         )
 
-        def frame_step_span(idx: int):
-            """--profile_dir: mark one serial frame as a profiler step so
-            the XLA device trace is segmented by frame index (the
-            scheduler path marks strides instead — sched/scheduler.py).
-            A shared nullcontext when profiling is off."""
-            if not args.profile_dir:
-                return contextlib.nullcontext()
-            return jax.profiler.StepTraceAnnotation("frame", step_num=idx)
-
+        from sartsolver_tpu.frameloop import FrameLoop
         from sartsolver_tpu.utils.prefetch import FramePrefetcher
 
         # Multi-host: every process runs the (collective) frame loop, only
@@ -1278,7 +1274,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         # active there — it runs inside the jitted program, identically on
         # every process).
         isolate = not (args.fail_fast or args.multihost)
-        stop_state = {"interrupted": False}
 
         def stop_now() -> bool:
             """Group-boundary stop poll. Multihost (and file-mode fake
@@ -1292,11 +1287,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             if args.multihost or mh.pod_identity()[1] > 1:
                 return mh.agree_stop(local)
             return local
-
-        def degrade_event(message: str) -> None:
-            note_event(message)
-            if primary:
-                print(f"sartsolve: {message}", file=sys.stderr)
 
         with profiler_ctx, writer_ctx as writer, FramePrefetcher(
             composite_image, isolate_failures=isolate
@@ -1330,282 +1320,43 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "resident re-audit: " + "; ".join(issues)
                     )
 
-            _SDC_REPRODUCED = integ_mod.SDC_REPRODUCED
+            loop = FrameLoop(
+                writer=writer, nvoxel=nvoxel, summary=summary, telem=telem,
+                timer=timer, stop_poll=stop_now, isolate=isolate,
+                primary=primary, multihost=args.multihost,
+                sdc_policy=sdc_policy, on_event=note_event,
+                heartbeat=lambda: watchdog.beacon(watchdog.PHASE_FRAME_DONE),
+                reaudit=integ_tick, step_trace=bool(args.profile_dir),
+            )
 
-            def sdc_guarded(solve_fn):
-                """Recompute-once wrapper for the grouped loops
-                (docs/RESILIENCE.md §8): a group whose statuses carry
-                SDC_DETECTED is re-solved once — a transient MXU fault
-                does not reproduce, a resident fault does. The status
-                fetch synchronizes the pipeline; that is the documented
-                host-side cost of --integrity on grouped paths (the
-                in-solve check itself is the <2 percent device cost). Frames
-                still SDC after the recompute become FAILED rows at
-                write time."""
-                if sdc_policy is None:
-                    return solve_fn
-
-                def guarded(stack):
-                    result = solve_fn(stack)
-                    sdc = np.asarray(result.status) == SDC_DETECTED
-                    if sdc.any():
-                        sdc_policy.detected(int(sdc.sum()))
-                        sdc_policy.note_recompute(int(sdc.sum()))
-                        result = solve_fn(stack)
-                        repeat = np.asarray(result.status) == SDC_DETECTED
-                        if repeat.any():
-                            sdc_policy.detected(int(repeat.sum()))
-                    return result
-
-                return guarded
-
-            def record_failed(ftime, cam_times, err):
-                writer.add(failed_row(nvoxel), FRAME_FAILED, ftime,
-                           cam_times, iterations=-1)
-                summary.record_status(FRAME_FAILED, ftime)
-                # typed telemetry: the failure counter is keyed by the
-                # error class, so injected faults (SART_FAULT) and their
-                # real counterparts increment the same series
-                telem.record_frame(ftime, FRAME_FAILED, -1, None, None,
-                                   "failed", error=type(err).__name__)
-                watchdog.beacon(watchdog.PHASE_FRAME_DONE)
-                if primary:
-                    print(f"Frame at t={ftime}: FAILED "
-                          f"({type(err).__name__}: {err})", file=sys.stderr)
             # Solutions stay ON DEVICE on every path: one packed scalar
             # fetch per solve group, solution transfer deferred to the
             # async writer's thread, warm starts chained device-side
             # (parallel/sharded.DeviceSolveResult — a synchronous
             # host<->device round trip cost ~68 ms on the remote-attached
             # chip of 2026-07, vs ~9 ms of device work for a warm-started
-            # frame). In
-            # multi-host runs the packed scalars are replicated (each
-            # process reads its local copy) and the solution is
+            # frame). In multi-host runs the packed scalars are replicated
+            # (each process reads its local copy) and the solution is
             # asynchronously all-gathered for process 0's writer; all
             # collectives stay on the main thread.
 
-            def run_grouped(K, pad_tail, solve_group, label, items=None):
-                """Shared frame-group protocol for the batch and chain
-                loops: accumulate K frames, pad the final partial group
-                (so the already-compiled K-program is reused instead of
-                triggering a second XLA compile; padded outputs are
-                discarded), solve as one device program, write per frame.
+            def solve_cold(stack, f0, warm):
+                # independent frames (--no_guess): no seed, no carry
+                return solver.solve_batch(stack, local=use_local,
+                                          device_result=True)
 
-                The groups are PIPELINED one deep: group k's scalar fetch
-                (DeviceSolveResult materializes its packed array lazily)
-                is deferred until group k+1 has been staged and
-                dispatched — the dispatch needs only the device-resident
-                warm solution and host-side norms — so k's D2H round trip
-                and k+1's host-side staging overlap k/k+1 device compute
-                instead of serializing with it.
-
-                Availability (docs/RESILIENCE.md §5/§7): K is only the
-                STARTING group size — a dispatch that dies with
-                RESOURCE_EXHAUSTED halves the size and re-solves the same
-                frames (degrade.GroupSizeLadder; the reduction sticks),
-                and a stop request (SIGTERM/SIGINT) is honored at group
-                boundaries: no new group is dispatched, the in-flight
-                group drains, undispatched frames are left for --resume.
-
-                The printed value is the group's incremental wall clock
-                over the pipeline divided by the group size — the honest
-                steady-state per-frame cost, not one frame's own time —
-                and each frame's exact iteration count."""
-                ladder = degrade.GroupSizeLadder(K, on_event=degrade_event)
-                # The halving ladder is a PER-PROCESS decision, so it must
-                # stay off in multihost runs: one process re-dispatching a
-                # half-sized collective program while its peers run the
-                # full size would deadlock the pod (the same reasoning
-                # that forces frame-level fail-fast there). A multihost
-                # OOM therefore aborts fail-fast like any other device
-                # error — requeue with --resume and a smaller
-                # --chain_frames/--batch_frames.
-                active_ladder = None if args.multihost else ladder
-                pending = []
-                prev = None  # (result, metas, t_dispatch) awaiting write
-                last_done = None
-                write_ok = True  # False while a write_group is mid-flight
-
-                def write_group(result, metas, t_dispatch):
-                    nonlocal last_done, write_ok
-                    write_ok = False  # re-set True only on completion
-                    start = (t_dispatch if last_done is None
-                             else max(t_dispatch, last_done))
-                    # first scalar access blocks until THIS group's device
-                    # work completed (the next group is already dispatched)
-                    statuses = result.status
-                    now = _time.perf_counter()
-                    dt = now - start
-                    last_done = now
-                    # the interval spans everything since the previous
-                    # group finished — staging/dispatching the next group
-                    # and any frame-read stall included — so the timer row
-                    # says "pipelined wall", not plain solve time.
-                    # detail=: this interval lies INSIDE the frame-loop
-                    # phase, so it must not also feed the total line
-                    timer.add(f"solve {label} (pipelined wall)", dt,
-                              detail=True)
-                    per_frame_ms = dt * 1e3 / len(metas)
-                    for b, (_, ftime, cam_times) in enumerate(metas):
-                        if (sdc_policy is not None
-                                and int(statuses[b]) == SDC_DETECTED):
-                            # the group already recomputed once
-                            # (sdc_guarded): this frame's corruption
-                            # reproduced — FAILED row; the terminal
-                            # accounting may quarantine the run (exit 3)
-                            sdc_policy.record_terminal(ftime)
-                            record_failed(
-                                ftime, cam_times,
-                                integ_mod.IntegrityError(_SDC_REPRODUCED),
-                            )
-                            continue
-                        writer.add(result.solution_fetcher(b),
-                                   int(statuses[b]), ftime, cam_times,
-                                   iterations=int(result.iterations[b]))
-                        summary.record_status(int(statuses[b]), ftime)
-                        telem.record_frame(
-                            ftime, int(statuses[b]),
-                            int(result.iterations[b]),
-                            float(result.convergence[b]),
-                            per_frame_ms, label,
-                        )
-                        watchdog.beacon(watchdog.PHASE_FRAME_DONE)
-                        if primary:
-                            print(f"Processed in: {per_frame_ms} ms "
-                                  f"(average over {label} of {len(metas)}; "
-                                  f"{int(result.iterations[b])} iterations)")
-                    integ_tick(len(metas))
-                    write_ok = True
-
-                def drain_inflight():
-                    # write the already-dispatched group now, so rows
-                    # recorded after it stay in frame order
-                    nonlocal prev
-                    if prev is not None and write_ok:
-                        to_write, prev = prev, None
-                        write_group(*to_write)
-
-                def flush(final=False):
-                    """Dispatch pending frames in ladder-sized groups.
-
-                    Mid-run this is called exactly when a full group
-                    accumulated; ``final`` additionally dispatches a
-                    partial tail (padded up to the group size). The
-                    while-loop re-reads ``ladder.size`` so an OOM halving
-                    re-solves the SAME frames at the reduced size."""
-                    nonlocal prev
-                    while pending and (final
-                                       or len(pending) >= ladder.size):
-                        group = pending[:ladder.size]
-                        stack = np.stack([fr for fr, _, _ in group])
-                        if len(group) < ladder.size:
-                            stack = np.concatenate(
-                                [stack,
-                                 pad_tail(stack, ladder.size - len(group))])
-                        t0 = _time.perf_counter()
-                        try:
-                            # availability-wrapped dispatch (the same
-                            # wrapper the guarded_dispatch compile-audit
-                            # entry lowers through): beacon + OOM
-                            # classification against the ladder
-                            result, _oom = degrade.dispatch_guarded(
-                                lambda: solve_group(stack),
-                                ladder=active_ladder,
-                            )
-                        except RECOVERABLE_FRAME_ERRORS as err:
-                            if not isolate:
-                                raise
-                            # the group produced nothing: its frames all
-                            # fail, in order, after the in-flight group's
-                            # rows; the warm carry skips the dead group
-                            # (the previous chain result is still the
-                            # seed of the next)
-                            drain_inflight()
-                            for _, ftime, cam_times in group:
-                                record_failed(ftime, cam_times, err)
-                            del pending[:len(group)]
-                            continue
-                        if result is None:
-                            # OOM halved the ladder: re-solve the SAME
-                            # frames at the smaller size (the warm carry
-                            # is intact — the failed dispatch never
-                            # updated it)
-                            continue
-                        # swap BEFORE writing: if write_group raises,
-                        # `prev` already holds the new unwritten group for
-                        # the drain below (never the just-written one —
-                        # no double write)
-                        to_write, prev = prev, (result, group, t0)
-                        del pending[:len(group)]
-                        if to_write is not None:
-                            write_group(*to_write)
-
-                try:
-                    for item in (frames if items is None else items):
-                        if not pending and stop_now():
-                            # frame-group boundary stop: no new group is
-                            # started; the in-flight group drains below
-                            # and the run exits EXIT_INTERRUPTED
-                            stop_state["interrupted"] = True
-                            break
-                        if isinstance(item, FrameFailure):
-                            # keep rows frame-ordered: dispatch what is
-                            # pending, drain the in-flight group, then
-                            # record the dead frame (a rare-path pipeline
-                            # stall, only on actual failures)
-                            if pending:
-                                flush(final=True)
-                            drain_inflight()
-                            record_failed(item.time, item.camera_times,
-                                          item.error)
-                            continue
-                        pending.append(item)
-                        if len(pending) >= ladder.size:
-                            flush()
-                    if pending and not stop_state["interrupted"]:
-                        flush(final=True)
-                except BaseException as err:
-                    # Best-effort drain of the in-flight group: a
-                    # frame-read or solve error must not silently discard
-                    # up to K already-solved frames. Skipped when the
-                    # failure was a write itself (writing the NEXT group
-                    # would punch a frame hole into the file — the
-                    # non-contiguity that corrupts --resume) or a
-                    # KeyboardInterrupt (the drain's blocking device fetch
-                    # would make an abort appear ignored on a wedged
-                    # backend; with the CLI's shutdown handlers installed
-                    # the first Ctrl-C takes the graceful stop path
-                    # instead and the second dies by the signal, so this
-                    # branch guards library/embedded callers); its own
-                    # errors never mask the one already propagating.
-                    if (prev is not None and write_ok
-                            and not isinstance(err, KeyboardInterrupt)):
-                        try:
-                            write_group(*prev)
-                        except BaseException:
-                            pass
-                    raise
-                else:
-                    if prev is not None:
-                        write_group(*prev)  # normal path: errors propagate
-                finally:
-                    # consolidated degradation line in the run summary —
-                    # recorded on success AND aborts (a degraded run that
-                    # later dies must still show the reduced size)
-                    ladder_line = ladder.summary()
-                    if ladder_line:
-                        summary.record_event(ladder_line)
-
-            def run_batch_grouped(K, items=None):
-                run_grouped(
-                    K,
-                    # inert dark frames (independent solves, no carry)
-                    lambda stack, n: np.zeros((n, stack.shape[1])),
-                    sdc_guarded(lambda stack: solver.solve_batch(
-                        stack, local=use_local, device_result=True)),
-                    "batch",
-                    items=items,
-                )
+            def solve_warm(stack, f0, warm):
+                if args.chain_frames == 1:
+                    # one frame a program: the serial warm-start program
+                    return solver.solve_batch(
+                        stack, None if f0 is None else f0[None, :],
+                        local=use_local, device_result=True, warm=warm,
+                    )
+                # K frames a program (lax.scan carrying the previous
+                # solution): ONE packed scalar fetch per chain, per-frame
+                # results identical to serial dispatch (solve_chain docs)
+                return solver.solve_chain(stack, f0=f0, warm=warm,
+                                          local=use_local)
 
             def run_scheduled(K):
                 """Continuous batching (docs/PERFORMANCE.md §8): K lanes,
@@ -1616,7 +1367,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                 and the classic grouped loop (whose halving ladder CAN
                 shrink the batch — the scheduler's fixed lane count
                 cannot without recompiling) finishes the run at half
-                size."""
+                size. Returns True when a stop request ended the run
+                early."""
                 from sartsolver_tpu.sched import ContinuousBatcher
 
                 def sched_result(ftime, cam_times, status, iterations,
@@ -1744,8 +1496,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
                 batcher = ContinuousBatcher(
                     solver, lanes=K,
-                    on_result=sched_result, on_failed=record_failed,
-                    stop_check=stop_now, on_event=degrade_event,
+                    on_result=sched_result, on_failed=loop.record_failed,
+                    stop_check=stop_now, on_event=loop.announce,
                     isolate=isolate, integrity_policy=sdc_policy,
                     step_trace=bool(args.profile_dir),
                     ckpt_stride=args.solve_ckpt_stride or None,
@@ -1773,164 +1525,35 @@ def main(argv: Optional[List[str]] = None) -> int:
                 else:
                     frames_iter = iter(frames)
                 stats = batcher.run(frames_iter)
-                if stats.interrupted:
-                    stop_state["interrupted"] = True
-                if stats.leftover is not None:
-                    import itertools
+                if stats.leftover is None:
+                    return stats.interrupted
+                import itertools
 
-                    run_batch_grouped(
-                        max(K // 2, 1),
-                        items=itertools.chain(stats.leftover, frames_iter),
-                    )
+                return loop.run(
+                    itertools.chain(stats.leftover, frames_iter),
+                    max(K // 2, 1), solve_cold,
+                ) or stats.interrupted
 
-            if args.batch_frames > 1:
-                if args.no_continuous_batching or args.multihost:
-                    # classic run-to-slowest grouping; multihost keeps it
-                    # because the scheduler's per-stride retire/backfill
-                    # decisions would have to be replicated across
-                    # processes in lockstep with per-process prefetch
-                    # streams — the same desynchronization hazard that
-                    # forces frame-level fail-fast there
-                    run_batch_grouped(args.batch_frames)
-                else:
-                    run_scheduled(args.batch_frames)
-            elif args.chain_frames > 1 and not args.no_guess:
-                # Warm-start loop chained on device: K frames per program
-                # (lax.scan carrying the previous solution), ONE packed
-                # scalar fetch per chain instead of per frame — per-frame
-                # results identical to serial dispatch (solve_chain docs).
-                # Tail pads are copies of the last real frame: each
-                # warm-starts from its own converged solution and stalls
-                # in ~1 iteration.
-                chain_state = {
-                    "warm": None,
-                    "f0": (resume_state.last_solution
-                           if resume_state is not None else None),
-                }
-
-                def solve_chain_group(stack):
-                    # snapshot the warm carry so sdc_guarded's recompute
-                    # re-enters with the SAME seed (the first attempt
-                    # already swapped its own result in)
-                    snap = (chain_state["f0"], chain_state["warm"])
-
-                    def once(stack):
-                        chain_state["f0"], chain_state["warm"] = snap
-                        dres = solver.solve_chain(
-                            stack, f0=chain_state["f0"],
-                            warm=chain_state["warm"], local=use_local)
-                        chain_state["f0"] = None
-                        chain_state["warm"] = dres
-                        return dres
-
-                    return sdc_guarded(once)(stack)
-
-                run_grouped(
-                    args.chain_frames,
-                    lambda stack, n: np.repeat(stack[-1:], n, axis=0),
-                    solve_chain_group,
-                    "chain",
+            if not args.no_guess:
+                # the reference's loop: each frame warm-starts from the
+                # previous solution, --chain_frames of them a program; a
+                # chain's tail pads are copies of its last real frame
+                interrupted = loop.run(
+                    frames, args.chain_frames, solve_warm, warm_start=True,
+                    f0=(resume_state.last_solution
+                        if resume_state is not None else None),
                 )
+            elif (args.batch_frames > 1 and not args.no_continuous_batching
+                  and not args.multihost):
+                interrupted = run_scheduled(args.batch_frames)
             else:
-                warm_dev = None  # device-chained warm start
-                f0_host: Optional[np.ndarray] = None  # host warm / resume seed
-                if resume_state is not None and not args.no_guess:
-                    f0_host = resume_state.last_solution
-                # Multihost stop polls are a host allgather; per-frame
-                # that round trip would rival the ~9 ms warm-frame solve
-                # itself, so poll every 4th frame there (the stride is
-                # identical on every process — the frame streams are —
-                # so the collective cadence stays agreed). Single-host
-                # polls are a local flag read: every frame.
-                stop_stride = 4 if args.multihost else 1
-                for idx, item in enumerate(frames):
-                    if idx % stop_stride == 0 and stop_now():
-                        # per-frame boundary stop (the serial loop's
-                        # group size is 1): already-written frames are
-                        # flushed on exit, the rest resume later. The
-                        # AGREED boundary is pinned into every host's
-                        # summary: the signal lands at different
-                        # instants per host and the multihost poll is
-                        # strided, so a host's local view of "where the
-                        # stop happened" can be up to stop_stride-1
-                        # frames off the pod's — the summaries must all
-                        # name the one boundary the pod stopped at.
-                        stop_state["interrupted"] = True
-                        note_event(
-                            f"stop agreed at frame boundary {idx}"
-                        )
-                        break
-                    if isinstance(item, FrameFailure):
-                        record_failed(item.time, item.camera_times,
-                                      item.error)
-                        continue  # warm start carries over the dead frame
-                    frame, ftime, cam_times = item
-                    t0 = _time.perf_counter()
-                    try:
-                        with frame_step_span(idx):
-                            dres = solver.solve_batch(
-                                np.asarray(frame)[None, :],
-                                None if f0_host is None
-                                else f0_host[None, :],
-                                local=use_local, device_result=True,
-                                warm=warm_dev,
-                            )
-                    except RECOVERABLE_FRAME_ERRORS as err:
-                        if not isolate:
-                            raise
-                        # staging/dispatch failed for THIS frame only; the
-                        # previous warm start (and an unconsumed resume
-                        # seed) stays valid for the next frame
-                        record_failed(ftime, cam_times, err)
-                        continue
-                    status = int(dres.status[0])
-                    if sdc_policy is not None and status == SDC_DETECTED:
-                        # escalation (RESILIENCE.md §8): recompute once
-                        # with the SAME seed; a repeat means the resident
-                        # state is corrupt — FAILED row, and the previous
-                        # warm start stays the next frame's seed
-                        sdc_policy.detected()
-                        sdc_policy.note_recompute()
-                        try:
-                            dres = solver.solve_batch(
-                                np.asarray(frame)[None, :],
-                                None if f0_host is None
-                                else f0_host[None, :],
-                                local=use_local, device_result=True,
-                                warm=warm_dev,
-                            )
-                        except RECOVERABLE_FRAME_ERRORS as err:
-                            if not isolate:
-                                raise
-                            record_failed(ftime, cam_times, err)
-                            continue
-                        status = int(dres.status[0])
-                        if status == SDC_DETECTED:
-                            sdc_policy.detected()
-                            sdc_policy.record_terminal(ftime)
-                            record_failed(
-                                ftime, cam_times,
-                                integ_mod.IntegrityError(_SDC_REPRODUCED),
-                            )
-                            continue
-                    f0_host = None  # resume seed consumed; chain on device
-                    warm_dev = None if args.no_guess else dres
-                    writer.add(dres.solution_fetcher(0), status,
-                               ftime, cam_times,
-                               iterations=int(dres.iterations[0]))
-                    summary.record_status(status, ftime)
-                    watchdog.beacon(watchdog.PHASE_FRAME_DONE)
-                    elapsed_ms = (_time.perf_counter() - t0) * 1e3
-                    telem.record_frame(
-                        ftime, status, int(dres.iterations[0]),
-                        float(dres.convergence[0]), elapsed_ms, "frame",
-                    )
-                    # detail=: per-frame rows lie inside the frame-loop
-                    # phase — shown, but excluded from the total line
-                    timer.add("solve frame", elapsed_ms / 1e3, detail=True)
-                    integ_tick(1)
-                    if primary:
-                        print(f"Processed in: {elapsed_ms} ms")
+                # classic run-to-slowest grouping; multihost keeps it
+                # because the scheduler's per-stride retire/backfill
+                # decisions would have to be replicated across processes
+                # in lockstep with per-process prefetch streams — the same
+                # desynchronization hazard that forces frame-level
+                # fail-fast there
+                interrupted = loop.run(frames, args.batch_frames, solve_cold)
 
         _mark("frame loop (solve + prefetch + flush)")
         if primary:
@@ -1965,7 +1588,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         # the last boundary check can only mean every frame completed —
         # reporting THAT as "interrupted, requeue me" would make a
         # scheduler re-run a finished job (and mask EXIT_PARTIAL).
-        interrupted = stop_state["interrupted"]
         if primary and (summary.n_failed or summary.had_retries()
                         or summary.events or interrupted or args.timing):
             print(summary.format())

@@ -768,17 +768,25 @@ def fused_selftest() -> None:
     a wrong sweep raises here with the compiler's message, so a broken
     kernel stops the run instead of quietly demoting it to the two-matmul
     path. A pass is remembered per backend.
+
+    The inputs are made inside the one program, which is released with
+    it. Eager ``jnp`` inputs would each leave a cached program loaded on
+    the device, laid out differently from process to process, ahead of
+    the matrix staged next; the matrix's place in HBM, and with it the
+    sweep's speed, then changed between runs of the same command (v5e).
     """
     backend = jax.default_backend()
     if backend in _selftest_passed:
         return
-    rtm = jnp.ones((8, 256), jnp.float32)
-    w = jnp.full((1, 8), 0.5, jnp.float32)
-    f = jnp.zeros((1, 256), jnp.float32)
+
+    def sweep():
+        rtm = jnp.ones((8, 256), jnp.float32)
+        w = jnp.full((1, 8), 0.5, jnp.float32)
+        f = jnp.zeros((1, 256), jnp.float32)
+        return fused_sweep(rtm, w, f, [], lambda fp, bp: fp + bp)
+
     try:
-        f_new, fitted = jax.jit(
-            lambda r, w, f: fused_sweep(r, w, f, [], lambda fp, bp: fp + bp)
-        )(rtm, w, f)
+        f_new, fitted = jax.jit(sweep)()
         f_new, fitted = np.asarray(f_new), np.asarray(fitted)
     except Exception as err:  # sart-lint: disable=SL006
         raise RuntimeError(

@@ -454,3 +454,73 @@ def test_timing_summary_printed(world, capsys):
     # fused-path provenance line (VERDICT r3 next #4); the fp64 CPU
     # profile never fuses, so 'off'/'not traced' variants are acceptable
     assert "fused sweep: requested=" in out
+
+
+_SART_ENV = ("SART_SCHEDULE_STRIDE", "SART_SPARSE_RTM", "SART_LOWRANK_RTM",
+             "SART_INTEGRITY")
+
+
+def _parsed(flags):
+    """Flags parsed and validated as ``main`` does (``_validate`` gives
+    --sparse_rtm and --lowrank_rtm their env-or-off defaults); two
+    placeholder inputs satisfy the input-count check."""
+    from sartsolver_tpu.cli import _validate, build_parser
+
+    args = build_parser().parse_args(list(flags) + ["rtm.h5", "img.h5"])
+    _validate(args)
+    return args
+
+
+@pytest.mark.parametrize("config", ["rtm-wide-fp32", "rtm-wide-fp32-4chip"])
+def test_solver_options_matches_benchmark_mirror(config, monkeypatch):
+    """The benchmark builds its SolverOptions with its own mirror of the
+    CLI; for each benchmark configuration's flags the mirror must equal
+    what the CLI builds."""
+    import json
+    import os
+
+    from benchmark.traffic.warm_series import _solver_options
+    from sartsolver_tpu.cli import schedule_stride, solver_options
+
+    for name in _SART_ENV:
+        monkeypatch.delenv(name, raising=False)
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmark", "configs",
+                           f"{config}.json")) as f:
+        flags = json.load(f)["flags"]
+    args = _parsed(flags)
+    cli_opts = solver_options(args, schedule_stride=schedule_stride(args),
+                              integrity=bool(args.integrity))
+    assert _solver_options(args) == cli_opts
+
+
+@pytest.mark.parametrize("flags,profile", [
+    ((), "device"),
+    (("--use_cpu", "-L", "-m", "40"), "cpu"),
+    (("--rtm_dtype", "bfloat16", "--sparse_rtm", "auto",
+      "--schedule_stride", "4"), "device"),
+])
+def test_solver_options_profiles(flags, profile, monkeypatch):
+    """One builder for both profiles: --use_cpu gives the fp64 CPU-parity
+    options, the device profile forwards the storage and sparsity flags;
+    the stride and integrity arguments land as given."""
+    from sartsolver_tpu.cli import schedule_stride, solver_options
+    from sartsolver_tpu.config import SolverOptions
+
+    for name in _SART_ENV:
+        monkeypatch.delenv(name, raising=False)
+    args = _parsed(flags)
+    opts = solver_options(args, schedule_stride=schedule_stride(args),
+                          integrity=True)
+    assert opts.integrity is True
+    assert opts.schedule_stride == (args.schedule_stride or 16)
+    assert opts.max_iterations == args.max_iterations
+    assert opts.logarithmic == args.logarithmic
+    if profile == "cpu":
+        assert opts == SolverOptions.cpu_parity(
+            logarithmic=True, max_iterations=40, integrity=True)
+    else:
+        assert opts.dtype == "float32"
+        assert opts.rtm_dtype == args.rtm_dtype
+        assert opts.sparse_rtm == args.sparse_rtm
+        assert opts.lowrank_rtm == "off"

@@ -190,6 +190,38 @@ def test_auto_declines_off_tpu_and_raises_on_tpu_kernel_failure(monkeypatch):
     assert not fs._selftest_passed  # a failure is never remembered
 
 
+def test_selftest_is_one_program(monkeypatch):
+    """The self-test lowers one program, which makes its own inputs: an
+    eager input would leave a cached program loaded on the device ahead
+    of the matrix, laid out differently from run to run."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    from sartsolver_tpu.ops import fused_sweep as fs
+
+    def sweep(rtm, w, f, aux, update):  # the kernel's sums, in plain jnp
+        f_new = update(f, w @ rtm)
+        return f_new, f_new @ rtm.T
+
+    monkeypatch.setattr(fs, "_selftest_passed", set())
+    monkeypatch.setattr(fs, "fused_sweep", sweep)
+    jax.clear_caches()
+    lowered = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+
+    monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        fs.fused_selftest()
+    finally:
+        monitoring.unregister_event_duration_listener(on_event)
+    assert len(lowered) == 1
+    assert fs._selftest_passed == {jax.default_backend()}
+
+
 def test_scoped_vmem_model():
     """Pin the scoped-VMEM accounting to what TPU v5e measurements showed
     (2026-07-29): the benchmark shapes must be eligible, with the raised
